@@ -88,7 +88,8 @@ def arch_msm_table():
     traces = [registry.scenario(f"lm.{a}.{s}") for a, s in cells]
     for (arch, shape), an in zip(cells, msm.analyze_suite(traces)):
         red = min(an.baseline_traffic / max(an.sweep[960 * MB], 1e-9), 999)
-        policy = msm.recommend(shape, configs.get(arch).n_params())
+        policy = msm.recommend(shape, configs.get(arch).n_params(),
+                               chips=256)   # one 16x16 production pod
         print(f"{arch:24s} {shape:10s} 960MB-filter={red:6.1f}x  "
               f"msm={policy.name:16s} ({policy.describe()})")
 

@@ -75,8 +75,8 @@ def main(argv=None) -> int:
             print(f.format())
         waived_n = len(findings) - len(unwaived)
         print(f"repro.check: {len(facts)} pallas_call(s) across "
-              f"{len(names)} case(s): {len(unwaived)} finding(s)"
-              + (f", {waived_n} waived" if waived_n else ""))
+              f"{len(names)} case(s): {len(unwaived)} finding(s), "
+              f"{waived_n} waived")
     return len(unwaived)
 
 

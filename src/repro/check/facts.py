@@ -277,13 +277,22 @@ def _collect_dots(kjaxpr) -> list[DotFacts]:
 _SRC_RE = re.compile(r"(\S+\.py):(\d+)")
 
 
-def _src_of(name_and_src_info) -> tuple[str, str, int]:
-    text = str(name_and_src_info)
-    name = getattr(name_and_src_info, "name", None) or text.split(" ")[0]
+def _src_of(debug_info) -> tuple[str, str, int]:
+    """(kernel name, file, line) from the kernel jaxpr's debug info, whose
+    ``func_src_info`` reads ``"<name> at <file>.py:<line>"``."""
+    text = getattr(debug_info, "func_src_info", None) or ""
+    name = getattr(debug_info, "func_name", None) or "<unknown>"
     m = _SRC_RE.search(text)
     if m:
         return name, m.group(1), int(m.group(2))
     return name, "<unknown>", 0
+
+
+def _block_dim(dim) -> int:
+    """Block extent of one BlockMapping dim: ``Blocked(n)`` -> n; squeezed
+    (``None``-sized) dims -> 1."""
+    size = getattr(dim, "block_size", dim)
+    return int(size) if isinstance(size, (int, np.integer)) else 1
 
 
 def _memory_space_name(block_aval) -> str:
@@ -301,7 +310,7 @@ def _memory_space_name(block_aval) -> str:
 def _facts_from_eqn(eqn, case: str) -> KernelFacts:
     gm = eqn.params["grid_mapping"]
     kernel_jaxpr = eqn.params["jaxpr"]
-    name, src_file, src_line = _src_of(eqn.params.get("name_and_src_info"))
+    name, src_file, src_line = _src_of(kernel_jaxpr.debug_info)
     grid = tuple(int(g) for g in gm.grid)
 
     n_index = int(getattr(gm, "num_index_operands", 0))
@@ -317,10 +326,8 @@ def _facts_from_eqn(eqn, case: str) -> KernelFacts:
     mappings = list(gm.block_mappings)
 
     def block_facts(bm, role, i, var) -> BlockFacts:
-        sds = bm.array_shape_dtype
-        block_shape = tuple(
-            int(b) if isinstance(b, (int, np.integer)) else 1
-            for b in bm.block_shape)
+        sds = bm.array_aval
+        block_shape = tuple(_block_dim(b) for b in bm.block_shape)
         unguarded, guarded = stores.get(var, (0, 0)) if role == "out" \
             else (0, 0)
         return BlockFacts(
@@ -329,7 +336,7 @@ def _facts_from_eqn(eqn, case: str) -> KernelFacts:
             array_shape=tuple(int(s) for s in sds.shape),
             dtype=_dtype_name(sds.dtype),
             block_shape=block_shape,
-            memory_space=_memory_space_name(bm.block_aval),
+            memory_space=_memory_space_name(bm.transformed_block_aval),
             block_indices=_eval_index_map(
                 bm.index_map_jaxpr, grid, len(block_shape)),
             unguarded_stores=int(unguarded),

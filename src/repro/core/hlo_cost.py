@@ -66,20 +66,6 @@ class HloCost:
     collective_by_kind: dict
 
 
-def raw_cost_analysis(compiled) -> dict:
-    """XLA's own (un-trip-expanded) cost properties, version-normalized.
-
-    ``compiled.cost_analysis()`` returns a dict on newer jax but a
-    one-element list of dicts on older releases (one entry per executable);
-    every consumer that wants the raw numbers next to :func:`analyze_hlo_cost`
-    should go through this accessor instead of indexing the raw return.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
-
-
 def _parse_computations(
     text: str, lhs_shapes: dict[str, tuple[int, ...]]
 ) -> tuple[dict[str, "_Comp"], str]:

@@ -167,6 +167,9 @@ def min_tile(dtype_itemsize: int) -> tuple[int, int]:
 # overlaps the current compute step; the R5 footprint rule charges each
 # in/out block twice and scratch once.
 PALLAS_PIPELINE_BUFFERS = 2
-PALLAS_VMEM_BUDGET = TPU_V5E.vmem_capacity
+# Mosaic's scoped-VMEM limit on v5e: a kernel whose pipeline buffers and
+# scratch exceed it is refused at compile time ("scoped allocation ... limit
+# 16.00M"), however much of the 128 MB physical VMEM is free.
+PALLAS_VMEM_BUDGET = 16 * MB
 # SMEM holds scalars/control state only; budget is deliberately tight.
 PALLAS_SMEM_BUDGET = 1 * MB

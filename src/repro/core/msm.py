@@ -120,8 +120,12 @@ def compose(name: str, **overrides) -> MemoryPolicy:
     return replace(base, **overrides) if overrides else base
 
 
-def recommend(shape_name: str, n_params: float) -> MemoryPolicy:
-    """Pick the software-MSM for a workload cell, like choosing a COPA SKU."""
+def recommend(shape_name: str, n_params: float, *, chips: int) -> MemoryPolicy:
+    """Pick the software-MSM for a workload cell, like choosing a COPA SKU.
+
+    ``chips`` is the number of chips that hold one copy of the parameters
+    and optimizer state between them (``sharding.partition.
+    param_shard_count``): the capacity the training recipe must fit."""
     from repro.sharding.optflags import opt
 
     def finish(p: MemoryPolicy) -> MemoryPolicy:
@@ -136,7 +140,7 @@ def recommend(shape_name: str, n_params: float) -> MemoryPolicy:
         # Models too large for fp32 optimizer residency get the large-model MSM
         # (bf16 moments + full remat), exactly the capacity-driven
         # specialization argument of the paper.
-        big = n_params * 14 > 0.70 * TPU_V5E.hbm_capacity * 256
+        big = n_params * 14 > 0.70 * TPU_V5E.hbm_capacity * chips
         return finish(TRAIN_LARGE_MSM if big else TRAIN_MSM)
     if shape_name.startswith("prefill"):
         return finish(PREFILL_MSM)

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -92,7 +91,7 @@ def pipeline_apply(block_fn, stage_params, x, *, mesh: Mesh,
         return jax.lax.psum(outputs, axis)
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(spec_p, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(spec_p, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x)

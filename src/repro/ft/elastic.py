@@ -12,7 +12,10 @@
   same checkpoint restores onto the smaller mesh).
 * ``train_segment(state, steps)`` runs until it returns (completed) or
   raises (hang/preemption) — the runner saves, rebuilds the mesh with
-  whatever devices are now healthy, and resumes.
+  whatever devices are now healthy, and resumes. The segment advances
+  ``state.step`` as it completes steps; one that raises before completing
+  any step since its start (a compile refusal, an out-of-memory, a bad
+  argument) would only fail the same way again, so it is not restarted.
 
 On real fleets mesh health comes from the cluster scheduler; here
 ``mesh_factory`` abstracts it (tests inject shrinking device sets).
@@ -107,11 +110,14 @@ class ElasticRunner:
             state = self.build_state(mesh, start)
             state.mesh = mesh
             state.restarts = restarts
+            first_step = state.step
             try:
                 state = self.train_segment(self, state, max_steps)
                 self.ckpt.wait()
                 return state
             except Exception as e:  # noqa: BLE001 — restart-able failure
+                if state.step == first_step:
+                    raise
                 restarts += 1
                 if restarts > self.max_restarts:
                     raise
@@ -120,6 +126,10 @@ class ElasticRunner:
                 time.sleep(0.1)
 
     def maybe_save(self, state: RunState, force: bool = False):
+        """Checkpoint every ``save_every`` steps, or now with ``force``;
+        ``save_every <= 0`` never checkpoints."""
+        if self.save_every <= 0:
+            return
         if force or (state.step > 0 and state.step % self.save_every == 0):
             self.ckpt.save_async(
                 state.step,

@@ -102,11 +102,14 @@ def _prep(q, k, v, out, lse, dout):
           .reshape(b * kvh, g, sq, d))
     dor = (dout.reshape(b, sq, kvh, g, dv).transpose(0, 2, 3, 1, 4)
            .reshape(b * kvh, g, sq, dv))
+    # Row statistics keep a trailing unit lane dim, so the kernel reads them
+    # as (G*Bq, 1) columns by merging leading dims only: Mosaic cannot cast
+    # a lane vector (G, Bq) into a column.
     lser = (lse.reshape(b, sq, kvh, g).transpose(0, 2, 3, 1)
-            .reshape(b * kvh, g, sq))
+            .reshape(b * kvh, g, sq, 1))
     delta = jnp.sum(out.astype(jnp.float32) * dout.astype(jnp.float32), -1)
     deltar = (delta.reshape(b, sq, kvh, g).transpose(0, 2, 3, 1)
-              .reshape(b * kvh, g, sq))
+              .reshape(b * kvh, g, sq, 1))
     kr = k.transpose(0, 2, 1, 3).reshape(b * kvh, skv, d)
     vr = v.transpose(0, 2, 1, 3).reshape(b * kvh, skv, dv)
     return qr, kr, vr, dor, lser, deltar
@@ -137,8 +140,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal=True,
             pl.BlockSpec((1, block_kv, d), lambda bh, qi, ki: (bh, ki, 0)),
             pl.BlockSpec((1, block_kv, dvd), lambda bh, qi, ki: (bh, ki, 0)),
             pl.BlockSpec((1, g, block_q, dvd), lambda bh, qi, ki: (bh, 0, qi, 0)),
-            pl.BlockSpec((1, g, block_q), lambda bh, qi, ki: (bh, 0, qi)),
-            pl.BlockSpec((1, g, block_q), lambda bh, qi, ki: (bh, 0, qi)),
+            pl.BlockSpec((1, g, block_q, 1), lambda bh, qi, ki: (bh, 0, qi, 0)),
+            pl.BlockSpec((1, g, block_q, 1), lambda bh, qi, ki: (bh, 0, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, g, block_q, d),
                                lambda bh, qi, ki: (bh, 0, qi, 0)),
@@ -158,8 +161,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, dout, *, causal=True,
             pl.BlockSpec((1, block_kv, d), lambda bh, ki, qi: (bh, ki, 0)),
             pl.BlockSpec((1, block_kv, dvd), lambda bh, ki, qi: (bh, ki, 0)),
             pl.BlockSpec((1, g, block_q, dvd), lambda bh, ki, qi: (bh, 0, qi, 0)),
-            pl.BlockSpec((1, g, block_q), lambda bh, ki, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, g, block_q), lambda bh, ki, qi: (bh, 0, qi)),
+            pl.BlockSpec((1, g, block_q, 1), lambda bh, ki, qi: (bh, 0, qi, 0)),
+            pl.BlockSpec((1, g, block_q, 1), lambda bh, ki, qi: (bh, 0, qi, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_kv, d), lambda bh, ki, qi: (bh, ki, 0)),

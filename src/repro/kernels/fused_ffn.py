@@ -25,17 +25,18 @@ def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, y_ref, acc_scr, *,
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    x = x_ref[...].astype(jnp.float32)           # (Bt, D)
-    wg = wg_ref[...].astype(jnp.float32)         # (D, Bf)
-    wu = wu_ref[...].astype(jnp.float32)
-    g = jax.lax.dot_general(x, wg, (((1,), (0,)), ((), ())),
+    # Operands stay in their storage dtype (bf16 on the MXU's native path);
+    # upcast copies of the weight tiles would not fit the scoped VMEM limit.
+    x = x_ref[...]                               # (Bt, D)
+    g = jax.lax.dot_general(x, wg_ref[...], (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    u = jax.lax.dot_general(x, wu, (((1,), (0,)), ((), ())),
+    u = jax.lax.dot_general(x, wu_ref[...], (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    h = (g * jax.lax.logistic(g)) * u            # silu(g) * u, (Bt, Bf)
-    wd = wd_ref[...].astype(jnp.float32)         # (Bf, D)
+    h = (g * jax.lax.logistic(g)) * u            # silu(g) * u, (Bt, Bf) f32
+    wd = wd_ref[...]                             # (Bf, D)
     acc_scr[...] += jax.lax.dot_general(
-        h, wd, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        h.astype(wd.dtype), wd, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     @pl.when(fi == num_f - 1)
     def _finalize():
@@ -43,7 +44,7 @@ def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, y_ref, acc_scr, *,
 
 
 def fused_ffn_pallas(x, w_gate, w_up, w_down, *, block_t: int = 256,
-                     block_f: int = 512, interpret: bool = False):
+                     block_f: int = 256, interpret: bool = False):
     """x: (T,D); w_gate/w_up: (D,F); w_down: (F,D) -> (T,D)."""
     t, d = x.shape
     f = w_gate.shape[1]

@@ -1,8 +1,10 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode for correctness
-validation; on TPU they compile natively. The model layer calls these via
-the ``pallas`` MSM policy.
+Kernels compile for the TPU by default. ``interpret=True`` runs the kernel
+body through the Pallas interpreter instead, which is how the CPU tests
+check them; the caller says which, so a run that lands on the wrong
+backend fails instead of silently interpreting. The model layer calls
+these via the ``pallas`` MSM policy.
 """
 from __future__ import annotations
 
@@ -16,28 +18,26 @@ from repro.kernels.fused_ffn import fused_ffn_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("causal", "scale"))
-def flash_attention_op(q, k, v, *, causal: bool = True, scale=None):
+@partial(jax.jit, static_argnames=("causal", "scale", "interpret"))
+def flash_attention_op(q, k, v, *, causal: bool = True, scale=None,
+                       interpret: bool = False):
     return flash_attention_pallas(q, k, v, causal=causal, scale=scale,
-                                  interpret=not _on_tpu())
+                                  interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("scale",))
-def flash_decode_op(q, k, v, kv_len, *, scale=None):
+@partial(jax.jit, static_argnames=("scale", "interpret"))
+def flash_decode_op(q, k, v, kv_len, *, scale=None, interpret: bool = False):
     return flash_decode_pallas(q, k, v, kv_len, scale=scale,
-                               interpret=not _on_tpu())
+                               interpret=interpret)
 
 
-@jax.jit
-def fused_ffn_op(x, w_gate, w_up, w_down):
-    return fused_ffn_pallas(x, w_gate, w_up, w_down, interpret=not _on_tpu())
+@partial(jax.jit, static_argnames=("interpret",))
+def fused_ffn_op(x, w_gate, w_up, w_down, *, interpret: bool = False):
+    return fused_ffn_pallas(x, w_gate, w_up, w_down, interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("chunk",))
-def ssd_scan_op(x, dt, A, b_, c_, chunk: int = 128):
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssd_scan_op(x, dt, A, b_, c_, chunk: int = 128, *,
+                interpret: bool = False):
     return ssd_scan_pallas(x, dt, A, b_, c_, chunk=chunk,
-                           interpret=not _on_tpu())
+                           interpret=interpret)

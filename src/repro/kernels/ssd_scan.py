@@ -5,8 +5,9 @@ dimension so the (P x N) SSM state lives in VMEM scratch across chunks —
 the inter-chunk recurrence never touches HBM. Within a chunk, the quadratic
 "dual form" (C B^T ⊙ decay) runs on (L x L) VMEM tiles.
 
-HBM traffic: x, dt, B, C, y once each + nothing for the state — the
-paper's traffic-filtering argument applied to the SSM working set.
+HBM traffic: x, dt, the cumulative log-decay (as a column and a row), B,
+C, y once each + nothing for the state — the paper's traffic-filtering
+argument applied to the SSM working set.
 """
 from __future__ import annotations
 
@@ -18,12 +19,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_scr, *,
-                chunk: int, num_chunks: int):
-    # check: waive[R1] — dt streams as (1, chunk) row slabs: the sublane dim
-    # is deliberately 1 (one (b,h) row per grid step, chunk on the lane dim);
-    # Mosaic pads the single sublane to a full tile and the slab walks in
-    # lockstep with the x/b/c chunk blocks, so alignment costs nothing here.
+def _ssd_kernel(x_ref, dt_ref, segc_ref, segr_ref, b_ref, c_ref, y_ref,
+                st_scr, *, chunk: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -32,19 +29,15 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_scr, *,
 
     x = x_ref[...].reshape(chunk, -1).astype(jnp.float32)      # (L, P)
     dt = dt_ref[...].reshape(chunk, 1).astype(jnp.float32)     # (L, 1)
-    a = a_ref[pl.program_id(0)]                                # scalar A_h (<0)
+    li = segc_ref[...].reshape(chunk, 1)                       # (L, 1)
+    lj = segr_ref[...].reshape(1, chunk)                       # (1, L)
     b = b_ref[...].reshape(chunk, -1).astype(jnp.float32)      # (L, N)
     c = c_ref[...].reshape(chunk, -1).astype(jnp.float32)      # (L, N)
-
-    da = dt * a                                                # (L,1)
-    seg = jnp.cumsum(da, axis=0)                               # (L,1)
-    total = seg[chunk - 1, 0]
+    total = li[chunk - 1:, :]                                  # (1, 1)
 
     # intra-chunk: (C B^T ⊙ decay ⊙ dt_j) X
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (L,L)
-    li = seg                                                    # (L,1)
-    lj = seg.reshape(1, chunk)
     decay = jnp.exp(li - lj)
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
@@ -55,14 +48,16 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_scr, *,
 
     # inter-chunk: y += (C exp(seg)) @ state_in ; state update
     st_in = st_scr[...]                                         # (N, P)
-    c_decay = c * jnp.exp(seg)                                  # (L,N)
+    c_decay = c * jnp.exp(li)                                   # (L,N)
     y += jax.lax.dot_general(c_decay, st_in, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    decay_out = jnp.exp(total - seg)                            # (L,1)
+    decay_out = jnp.exp(total - li)                             # (L,1)
     bwt = b * decay_out      # dt already folded into xdt       # (L,N)
     st_new = jax.lax.dot_general(bwt, xdt, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (N,P)
-    st_scr[...] = st_new + jnp.exp(total) * st_in
+    # (1,1) -> (N,1) -> (N,P): Mosaic broadcasts one tiled dim at a time.
+    carry = jnp.exp(jnp.broadcast_to(total, (st_in.shape[0], 1)))
+    st_scr[...] = st_new + carry * st_in
 
     y_ref[...] = y.reshape(y_ref.shape).astype(y_ref.dtype)
 
@@ -80,19 +75,26 @@ def ssd_scan_pallas(x, dt, A, b_, c_, *, chunk: int = 128,
     nc = s // chunk
 
     xr = x.transpose(0, 2, 1, 3).reshape(bsz * h, s, p)
+    # dt and the within-chunk cumulative log-decay seg = cumsum(dt * A) enter
+    # as (S, 1) columns, and seg also as a (1, S) row: the kernel needs both
+    # orientations, and Mosaic has neither a cumsum nor a lane<->sublane
+    # reshape. Each block spans the full unit dim, so every block is aligned.
     dtr = dt.transpose(0, 2, 1).reshape(bsz * h, s)
-    ar = jnp.repeat(A.astype(jnp.float32)[None, :], bsz, 0).reshape(bsz * h)
+    da = dtr.astype(jnp.float32) * jnp.tile(A.astype(jnp.float32), bsz)[:, None]
+    seg = jnp.cumsum(da.reshape(bsz * h, nc, chunk), axis=-1).reshape(bsz * h, s)
     br = jnp.repeat(b_[:, None], h, 1).reshape(bsz * h, s, n)
     cr = jnp.repeat(c_[:, None], h, 1).reshape(bsz * h, s, n)
 
-    kernel = functools.partial(_ssd_kernel, chunk=chunk, num_chunks=nc)
+    kernel = functools.partial(_ssd_kernel, chunk=chunk)
+    col = pl.BlockSpec((1, chunk, 1), lambda bh, _, ci: (bh, ci, 0))
     out = pl.pallas_call(
         kernel,
         grid=(bsz * h, 1, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda bh, _, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, _, ci: (bh, ci)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            col,
+            col,
+            pl.BlockSpec((1, 1, chunk), lambda bh, _, ci: (bh, 0, ci)),
             pl.BlockSpec((1, chunk, n), lambda bh, _, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, _, ci: (bh, ci, 0)),
         ],
@@ -100,5 +102,5 @@ def ssd_scan_pallas(x, dt, A, b_, c_, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((bsz * h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xr, dtr, ar, br, cr)
+    )(xr, dtr[..., None], seg[..., None], seg[:, None, :], br, cr)
     return out.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)

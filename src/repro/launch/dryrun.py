@@ -27,10 +27,11 @@ import jax
 import repro.configs as configs
 from repro.configs.base import cell_is_runnable
 from repro.core.hloparse import parse_collectives
-from repro.core.hlo_cost import analyze_hlo_cost, raw_cost_analysis
+from repro.core.hlo_cost import analyze_hlo_cost
 from repro.core.roofline import model_flops_lm
-from repro.launch.mesh import make_production_mesh, set_default_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs, optim_config_for
+from repro.sharding.partition import param_shard_count
 from repro.core import msm
 from repro.train import make_train_step
 from repro.serve.step import make_decode_step, make_prefill_step
@@ -98,13 +99,14 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
 
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
-    policy = msm.recommend(shape.name, cfg.n_params())
+    policy = msm.recommend(shape.name, cfg.n_params(),
+                           chips=param_shard_count(mesh))
     kind, model, abstract_args, out_sh = input_specs(arch, shape_name, mesh,
                                                      policy)
     step_fn, jit_kw = build_step(kind, model, policy, abstract_args,
                                  mesh=mesh, global_batch=shape.global_batch)
 
-    set_default_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
     lowered = jax.jit(step_fn, out_shardings=out_sh,
                       **jit_kw).lower(*abstract_args)
     t_lower = time.time() - t0
@@ -112,7 +114,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
     t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = raw_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     coll = parse_collectives(hlo_text)
     # trip-count-expanded accounting (XLA counts while bodies once)

@@ -1,56 +1,31 @@
-"""Production mesh construction (assignment-specified shapes).
+"""Mesh construction (assignment-specified shapes).
 
-A FUNCTION, not a module-level constant: importing this module must never
+Functions, not module-level constants: importing this module must never
 touch jax device state (smoke tests see 1 device; only dryrun.py forces 512).
 
-Also the jax version-compat seam: ``jax.sharding.AxisType`` /
-``jax.make_mesh(..., axis_types=...)`` and ``jax.sharding.set_mesh`` only
-exist on newer jax releases. Everything in this repo (and the subprocess
-test scripts) builds meshes through :func:`make_compat_mesh` and installs
-them through :func:`set_default_mesh`, which degrade gracefully on older
-jax: meshes are built without explicit axis types (the old default), and
-the ambient-mesh install becomes a no-op (all shardings in this codebase
-are passed explicitly as NamedShardings; the only implicit-mesh consumer,
-``sharding.partition.constrain``, already no-ops without an abstract mesh).
+Every mesh has Auto axes: all shardings in this codebase are passed
+explicitly as NamedShardings, and ``jax.make_mesh`` would otherwise make
+Explicit (sharding-in-types) axes.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=(Auto,) * n`` where the installed jax supports it."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_compat_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types on jax that has them."""
-    try:
-        return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
-    except TypeError:
-        # AxisType exists but make_mesh predates the axis_types kwarg.
-        return jax.make_mesh(shape, axes)
-
-
-def set_default_mesh(mesh) -> None:
-    """``jax.sharding.set_mesh`` where available; no-op on older jax."""
-    setter = getattr(jax.sharding, "set_mesh", None)
-    if setter is None:
-        return
-    setter(mesh)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` over the visible devices, with Auto axis types."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     data = data or (n // model)
-    return make_compat_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

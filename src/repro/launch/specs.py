@@ -7,12 +7,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 import repro.configs as configs
-from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import msm
 from repro.models import LanguageModel
 from repro.models.base import abstract_params
 from repro.sharding.partition import (batch_spec, cache_shardings,
-                                      param_shardings)
+                                      param_shard_count, param_shardings)
 from repro.train import OptimConfig, init_opt_state
 
 VLM_PATCHES = 256
@@ -22,11 +21,6 @@ WHISPER_ENC_LEN = 1500
 def sds(shape, dtype, mesh=None, spec=None):
     sharding = NamedSharding(mesh, spec) if mesh is not None else None
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-
-def model_for(cfg: ModelConfig, shape: ShapeConfig, policy=None) -> LanguageModel:
-    policy = policy or msm.recommend(shape.name, cfg.n_params())
-    return LanguageModel(cfg, impl=policy.attention_impl, remat=policy.remat)
 
 
 def abstract_model_params(model: LanguageModel, mesh: Mesh, fsdp: bool = True):
@@ -42,13 +36,16 @@ def abstract_model_params(model: LanguageModel, mesh: Mesh, fsdp: bool = True):
     return attach(aparams, shardings), shardings
 
 
-def optim_config_for(policy) -> OptimConfig:
+def optim_config_for(policy, **schedule) -> OptimConfig:
+    """The policy's optimizer recipe; ``schedule`` sets the OptimConfig
+    learning-rate fields (lr, warmup_steps, total_steps)."""
     return OptimConfig(
         moment_dtype="bfloat16" if policy.optimizer_dtype == "bfloat16" else "float32",
         master_weights=policy.master_weights,
         # RTN updates in the capacity-specialized recipe: the SR path costs a
         # params-sized u32/u64 RNG temp per step (~7 GiB/device at 236B).
         stochastic_rounding=False,
+        **schedule,
     )
 
 
@@ -104,8 +101,9 @@ def input_specs(arch: str, shape_name: str, mesh: Mesh, policy=None):
     a 1.1B model) and donation cannot alias."""
     cfg = configs.get(arch)
     shape = configs.SHAPES[shape_name]
-    policy = policy or msm.recommend(shape.name, cfg.n_params())
-    model = model_for(cfg, shape, policy)
+    policy = policy or msm.recommend(shape.name, cfg.n_params(),
+                                     chips=param_shard_count(mesh))
+    model = LanguageModel(cfg, impl=policy.attention_impl, remat=policy.remat)
     gb, seq = shape.global_batch, shape.seq_len
     bspec = batch_spec(mesh)
     tok_dtype = jnp.int32

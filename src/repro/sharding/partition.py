@@ -17,6 +17,8 @@ same mesh axis, the first (leftmost priority order below) wins.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -34,6 +36,14 @@ LOGICAL_RULES: list[tuple[str, tuple[str, ...]]] = [
 ]
 _RULES = dict(LOGICAL_RULES)
 _PRIORITY = {name: i for i, (name, _) in enumerate(LOGICAL_RULES)}
+
+
+def param_shard_count(mesh: Mesh) -> int:
+    """Chips that hold one copy of the parameters between them: the mesh
+    axes that LOGICAL_RULES shards over. A "pod" axis replicates."""
+    axes = {a for _, targets in LOGICAL_RULES for a in targets}
+    return math.prod(n for a, n in zip(mesh.axis_names, mesh.devices.shape)
+                     if a in axes)
 
 
 def resolve_spec(shape: tuple[int, ...], axes: tuple[str | None, ...],

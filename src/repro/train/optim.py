@@ -122,8 +122,10 @@ def apply_updates(params, grads, state, cfg: OptimConfig, rng=None):
     return jax.tree.unflatten(treedef, new_p), new_state, metrics
 
 
-def state_shardings(param_shardings_tree, cfg: OptimConfig, mesh):
-    """Optimizer state shards exactly like its parameters."""
+def state_shardings(param_shardings_tree, cfg: OptimConfig, mesh,
+                    grad_compression: str | None = None):
+    """Optimizer state (and the int8_ef error-feedback buffers) shards
+    exactly like its parameters."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     scalar = NamedSharding(mesh, PartitionSpec())
@@ -134,4 +136,6 @@ def state_shardings(param_shardings_tree, cfg: OptimConfig, mesh):
     }
     if cfg.master_weights:
         out["master"] = param_shardings_tree
+    if grad_compression == "int8_ef":
+        out["ef"] = param_shardings_tree
     return out

@@ -17,12 +17,17 @@ def _misaligned_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[:100, :100] * 2.0
 
 
-def bad_tile(x):
+def _waived_misaligned_kernel(x_ref, o_ref):
+    # check: waive[R1] — the one waiver in the repo, kept to test waivers.
+    o_ref[...] = x_ref[:100, :100] * 2.0
+
+
+def bad_tile(x, kernel=_misaligned_kernel):
     """R1: (100, 100) output blocks — neither lane (128) nor sublane (8 for
     f32) aligned, and not covering the full array dim. The input stays a
     full-array (aligned-by-exemption) block so only the output trips."""
     return pl.pallas_call(
-        _misaligned_kernel,
+        kernel,
         grid=(3, 3),
         in_specs=[pl.BlockSpec((256, 256), lambda i, j: (0, 0))],
         out_specs=pl.BlockSpec((100, 100), lambda i, j: (i, j)),
@@ -92,8 +97,8 @@ def _big_scratch_kernel(x_ref, o_ref, scr):
 
 
 def bad_footprint(x):
-    """R5: a (8192, 8192) f32 VMEM scratch is 256MB — double the per-core
-    VMEM budget on its own."""
+    """R5: a (8192, 8192) f32 VMEM scratch is 256MB — 16x the scoped VMEM
+    budget on its own."""
     return pl.pallas_call(
         _big_scratch_kernel,
         grid=(1,),
@@ -102,6 +107,11 @@ def bad_footprint(x):
         out_shape=jax.ShapeDtypeStruct((256, 256), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8192, 8192), jnp.float32)],
     )(x)
+
+
+def waived_tile(x):
+    """bad_tile's R1 finding, waived by a comment in the kernel body."""
+    return bad_tile(x, kernel=_waived_misaligned_kernel)
 
 
 # rule -> (wrapper, input ShapeDtypeStructs)

@@ -41,7 +41,7 @@ def test_scan_flops_counted_fully():
     script = textwrap.dedent("""
         import jax, jax.numpy as jnp, sys
         sys.path.insert(0, "src")
-        from repro.core.hlo_cost import analyze_hlo_cost, raw_cost_analysis
+        from repro.core.hlo_cost import analyze_hlo_cost
         def f(x, w):
             def body(c, _):
                 return jnp.tanh(c @ w), None
@@ -50,7 +50,7 @@ def test_scan_flops_counted_fully():
         w = jax.ShapeDtypeStruct((128, 128), jnp.float32)
         comp = jax.jit(f).lower(x, w).compile()
         c = analyze_hlo_cost(comp.as_text())
-        raw = raw_cost_analysis(comp)["flops"]
+        raw = comp.cost_analysis()["flops"]
         assert abs(c.dot_flops - 7 * 2 * 128**3) < 1e5, c.dot_flops
         assert raw < c.dot_flops / 3  # the undercount this module fixes
         print("ok")

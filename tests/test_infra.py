@@ -1,7 +1,12 @@
 """Infrastructure tests: data determinism, checkpoint atomicity/resharding,
-watchdog, elastic restart, HLO parsing."""
+watchdog, elastic restart, compile cache placement, HLO parsing."""
 import os
+import subprocess
+import sys
+import textwrap
 import time
+import uuid
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -75,9 +80,9 @@ def test_checkpoint_reshard_on_restore(tmp_path):
     """Restore onto a different sharding than saved (elastic contract)."""
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_compat_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     save(str(tmp_path), 1, {"w": jnp.arange(8.0)})
     sh = {"w": NamedSharding(mesh, PartitionSpec("data"))}
     _, out, _ = restore(str(tmp_path), shardings=sh)
@@ -156,6 +161,71 @@ def test_elastic_restart_resumes_from_checkpoint(tmp_path):
     assert crashes["n"] == 1
     # params reflect resumed progress (>= 10 increments minus lost tail)
     assert float(st.params["w"][0]) >= 9.0
+
+
+def test_elastic_does_not_restart_a_segment_without_progress(tmp_path):
+    """A segment that fails before completing its first step (a compile
+    refusal, an out-of-memory) re-raises at once: a restart would only
+    fail the same way."""
+    from repro.ft import ElasticRunner, RunState
+
+    builds = []
+
+    def build_state(mesh, restore_step):
+        builds.append(restore_step)
+        return RunState(params={}, opt_state={}, step=0)
+
+    def train_segment(runner, st, max_steps):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    runner = ElasticRunner(str(tmp_path), lambda: None, build_state,
+                           train_segment)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        runner.run(10)
+    assert builds == [None]
+
+
+# --- compile cache -----------------------------------------------------------
+
+_CACHE_PROBE = textwrap.dedent("""
+    import sys
+    import jax, jax.numpy as jnp
+    from repro.launch.runtime import enable_compile_cache
+    print(enable_compile_cache())
+    c = float(sys.argv[1])
+    jax.jit(lambda x: jnp.sin(x) * c)(jnp.ones(3)).block_until_ready()
+""")
+
+
+def test_compile_cache_goes_where_the_environment_says(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the launchers' cache entries
+    appear only there; without it, in the checkout's fixed .jax_cache."""
+    from repro.launch.runtime import REPO_CACHE_DIR
+
+    repo = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="src",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    nonce = str(uuid.uuid4().int % 10**9)   # a program no run compiled yet
+
+    def probe(extra_env):
+        out = subprocess.run([sys.executable, "-c", _CACHE_PROBE, nonce],
+                             cwd=repo, env=dict(env, **extra_env),
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.splitlines()[0]
+
+    def entries(d):
+        return set(os.listdir(d)) if os.path.isdir(d) else set()
+
+    assert probe({"JAX_COMPILATION_CACHE_DIR": str(tmp_path)}) == str(tmp_path)
+    ours = entries(tmp_path)
+    assert any(n.startswith("jit__lambda") for n in ours)
+    assert not ours & entries(REPO_CACHE_DIR)
+    before = entries(REPO_CACHE_DIR)
+    assert probe({}) == str(REPO_CACHE_DIR)
+    assert any(n.startswith("jit__lambda")
+               for n in entries(REPO_CACHE_DIR) - before)
 
 
 # --- HLO parsing ------------------------------------------------------------------

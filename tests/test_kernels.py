@@ -90,6 +90,44 @@ def test_ssd_scan_kernel(b, s, h, p, n, chunk):
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-3)
 
 
+@pytest.mark.parametrize("op", ["flash_attention", "flash_decode",
+                                "fused_ffn", "ssd_scan"])
+def test_ops_dispatch_runs_interpreted_when_asked(op):
+    """The jit'd dispatch wrappers compile for the TPU unless the caller
+    passes interpret=True, as every CPU test does."""
+    from repro.kernels import ops
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    f32 = jnp.float32
+    if op == "flash_attention":
+        q, k, v = (jax.random.normal(ks[i], (1, 256, 4, 64), f32)
+                   for i in range(3))
+        got = ops.flash_attention_op(q, k, v, interpret=True)
+        want = ref.flash_attention_ref(q, k, v, causal=True)
+    elif op == "flash_decode":
+        q = jax.random.normal(ks[0], (2, 4, 64), f32)
+        k, v = (jax.random.normal(ks[i], (2, 512, 2, 64), f32)
+                for i in (1, 2))
+        got = ops.flash_decode_op(q, k, v, 300, interpret=True)
+        want = ref.flash_decode_ref(q, k, v, 300)
+    elif op == "fused_ffn":
+        x = jax.random.normal(ks[0], (256, 128), f32) * 0.5
+        wg, wu = (jax.random.normal(ks[i], (128, 512), f32) * 0.05
+                  for i in (1, 2))
+        wd = jax.random.normal(ks[3], (512, 128), f32) * 0.05
+        got = ops.fused_ffn_op(x, wg, wu, wd, interpret=True)
+        want = ref.fused_ffn_ref(x, wg, wu, wd)
+    else:
+        x = jax.random.normal(ks[0], (1, 128, 2, 32), f32) * 0.5
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (1, 128, 2), f32))
+        A = -jnp.exp(jax.random.normal(ks[2], (2,), f32) * 0.3)
+        b_, c_ = (jax.random.normal(ks[i], (1, 128, 16), f32) * 0.3
+                  for i in (3, 4))
+        got = ops.ssd_scan_op(x, dt, A, b_, c_, chunk=64, interpret=True)
+        want, _ = ref.ssd_chunk_ref(x, dt, A, b_, c_)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-3)
+
+
 def test_ssd_jnp_chunked_matches_sequential():
     """The model-layer chunked SSD (lax.scan path used under pjit) agrees
     with the token-by-token recurrence for multiple chunk sizes."""

@@ -23,9 +23,9 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.distributed.pipeline import pipeline_apply
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_compat_mesh((4,), ("pipe",))
+    mesh = make_mesh((4,), ("pipe",))
     S, M, MB, D = 4, 8, 2, 16
     key = jax.random.PRNGKey(0)
     w = jax.random.normal(key, (S, D, D)) * 0.3      # one layer per stage

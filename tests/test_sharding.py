@@ -10,7 +10,7 @@ import pytest
 
 from jax.sharding import PartitionSpec
 
-from repro.launch.mesh import make_compat_mesh as _mesh
+from repro.launch.mesh import make_mesh as _mesh
 from repro.sharding.partition import resolve_spec
 
 
@@ -42,7 +42,7 @@ SUBPROC_SCRIPT = textwrap.dedent("""
     import numpy as np
     import repro.configs as C
     from repro.core import msm
-    from repro.launch.mesh import make_host_mesh, set_default_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.models import LanguageModel
     from repro.models.base import abstract_params
     from repro.sharding.partition import batch_spec, param_shardings
@@ -51,7 +51,7 @@ SUBPROC_SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding
 
     mesh = make_host_mesh(data=4, model=2)
-    set_default_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
     cfg = C.get("qwen3-moe-235b-a22b").smoke()
     model = LanguageModel(cfg)
     aparams = abstract_params(model.specs())

@@ -1,6 +1,13 @@
 """End-to-end behaviour tests for the whole system."""
 import jax
 import numpy as np
+import pytest
+
+import repro.configs as C
+
+# Training recipe per arch on one 16x16 pod (256 chips): only the two
+# ~236B MoE models need the bf16-moment, no-master recipe there.
+_POD_LARGE = {"qwen3-moe-235b-a22b", "deepseek-v2-236b"}
 
 
 def test_train_then_serve_roundtrip(tmp_path):
@@ -54,16 +61,24 @@ def test_training_reduces_loss_learnable_data():
     assert last < first - 1.0, (first, last)
 
 
-def test_msm_policy_selection():
-    """The software-MSM chooser composes per-domain policies (COPA SKUs)."""
+@pytest.mark.parametrize("arch,chips,want", [
+    ("tinyllama-1.1b", 1, "msm_train_large"),
+    ("granite-3-2b", 4, "msm_train"),
+    *[(arch, 256, "msm_train_large" if arch in _POD_LARGE else "msm_train")
+      for arch in C.ARCHS],
+])
+def test_msm_policy_selection(arch, chips, want):
+    """The software-MSM chooser composes per-domain policies (COPA SKUs),
+    sizing the training recipe to the chips that hold the state."""
     from repro.core import msm
 
-    small = msm.recommend("train_4k", 1e9)
-    big = msm.recommend("train_4k", 236e9)
-    assert small.name == "msm_train" and big.name == "msm_train_large"
-    assert big.optimizer_dtype == "bfloat16" and not big.master_weights
-    assert msm.recommend("long_500k", 1e9).kv_shard_axis == "data"
-    assert msm.recommend("decode_32k", 1e9).remat == "none"
+    policy = msm.recommend("train_4k", C.get(arch).n_params(), chips=chips)
+    assert policy.name == want
+    if want == "msm_train_large":
+        assert policy.optimizer_dtype == "bfloat16"
+        assert not policy.master_weights
+    assert msm.recommend("long_500k", 1e9, chips=chips).kv_shard_axis == "data"
+    assert msm.recommend("decode_32k", 1e9, chips=chips).remat == "none"
 
 
 def test_arch_traces_feed_copa_analysis():
