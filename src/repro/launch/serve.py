@@ -17,6 +17,8 @@ and SLO goodput (see ``repro.serve.sim`` / ``repro.serve.fleet``):
 from __future__ import annotations
 
 import argparse
+import contextlib
+import functools
 import time
 
 import jax
@@ -30,8 +32,37 @@ from repro.models import LanguageModel
 from repro.serve.step import make_decode_step
 
 
+# Emitted by JAX for every program it compiles or loads from the compile cache.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _mark_compile(event: str, duration_secs: float, **_) -> None:
+    """A ``serve.compile`` marker, a span of a few microseconds that holds
+    the compile's ``seconds``, in the profile if one runs."""
+    if event == COMPILE_EVENT:
+        with jax.profiler.TraceAnnotation("serve.compile", seconds=duration_secs):
+            pass
+
+
+@functools.cache
+def _listen_for_compiles() -> None:
+    """Registers ``_mark_compile`` once per process. The listener holds no
+    engine, so it keeps none alive."""
+    jax.monitoring.register_event_duration_secs_listener(_mark_compile)
+
+
 class ServingEngine:
-    """Minimal continuous-batching engine over the decode step."""
+    """Minimal continuous-batching engine over the decode step.
+
+    Under a running ``jax.profiler`` trace it writes host spans on the
+    profile's clock: ``serve.generate`` and ``serve.prefill`` around those
+    calls; one ``serve.step`` (``step_num``, ``pos``) per decode-step call,
+    holding ``serve.dispatch`` (building the position and key, and the
+    call) and, in generation, ``serve.fetch`` (the copy of the token to the
+    host, where the host waits on the device); and a ``serve.compile``
+    marker (``seconds``) for each program compiled or loaded from the
+    compile cache after ``compile`` returned.
+    """
 
     def __init__(self, model: LanguageModel, params, batch: int,
                  max_len: int, enc_len: int = 64):
@@ -42,6 +73,7 @@ class ServingEngine:
         self.cache = model.init_cache(batch, max_len, enc_len=enc_len)
         self.decode = jax.jit(make_decode_step(model), donate_argnums=(1,))
         self.lengths = np.zeros(batch, np.int32)
+        self.steps = 0          # decode-step calls over the engine's life
 
     def compile(self) -> float:
         """Compile the decode step for this engine's shapes before the first
@@ -51,29 +83,50 @@ class ServingEngine:
         self.decode = self.decode.lower(
             self.params, self.cache, tokens, jnp.int32(0),
             jax.random.PRNGKey(0)).compile()
+        _listen_for_compiles()
         return time.perf_counter() - t0
+
+    @contextlib.contextmanager
+    def _step(self, pos: int):
+        with jax.profiler.StepTraceAnnotation("serve.step",
+                                              step_num=self.steps, pos=pos):
+            yield
+        self.steps += 1
+
+    def _dispatch(self, tokens, pos: int, seed: int):
+        """Enqueue one decode step; its tokens stay on the device."""
+        with jax.profiler.TraceAnnotation("serve.dispatch"):
+            toks, self.cache = self.decode(
+                self.params, self.cache, tokens, jnp.int32(pos),
+                jax.random.PRNGKey(seed))
+        return toks
+
+    @staticmethod
+    def _fetch(toks) -> np.ndarray:
+        with jax.profiler.TraceAnnotation("serve.fetch"):
+            return np.asarray(toks)
 
     def prefill(self, prompts: np.ndarray):
         """Teacher-forced prefill via the decode step (token at a time —
         simple and exact; production prefill uses the chunked forward)."""
         b, plen = prompts.shape
         toks = None
-        for t in range(plen):
-            toks, self.cache = self.decode(
-                self.params, self.cache, prompts[:, t:t + 1],
-                jnp.int32(t), jax.random.PRNGKey(t))
+        with jax.profiler.TraceAnnotation("serve.prefill"):
+            for t in range(plen):
+                with self._step(t):
+                    toks = self._dispatch(prompts[:, t:t + 1], t, t)
         self.lengths[:] = plen
         return toks
 
     def generate(self, prompts: np.ndarray, steps: int):
-        next_tok = self.prefill(prompts)
-        out = [np.asarray(next_tok)]
-        pos = prompts.shape[1]
-        for i in range(steps - 1):
-            next_tok, self.cache = self.decode(
-                self.params, self.cache, next_tok, jnp.int32(pos + i),
-                jax.random.PRNGKey(1000 + i))
-            out.append(np.asarray(next_tok))
+        with jax.profiler.TraceAnnotation("serve.generate"):
+            next_tok = self.prefill(prompts)
+            out = [self._fetch(next_tok)]
+            pos = prompts.shape[1]
+            for i in range(steps - 1):
+                with self._step(pos + i):
+                    next_tok = self._dispatch(next_tok, pos + i, 1000 + i)
+                    out.append(self._fetch(next_tok))
         self.lengths += steps
         return np.concatenate(out, axis=1)
 
@@ -143,12 +196,26 @@ def main(argv=None):
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, cfg.vocab_size,
                            (args.batch, args.prompt_len)).astype(np.int32)
+    # Prefill ends where generate first waits on the device: its first
+    # token on the host. Decode is the rest; each token is copied to the host.
+    prefill, t_first = engine.prefill, []
+
+    def timed_prefill(p):
+        tok = jax.block_until_ready(prefill(p))
+        t_first.append(time.perf_counter())
+        return tok
+
+    engine.prefill = timed_prefill
     t0 = time.perf_counter()
-    toks = engine.generate(prompts, args.gen)   # host copies wait on device
-    dt = time.perf_counter() - t0
-    print(f"generated {toks.shape} tokens in {dt:.2f}s after "
-          f"{args.prompt_len} prefill steps "
-          f"({args.batch * args.gen / dt:.1f} tok/s, compile excluded)")
+    toks = engine.generate(prompts, args.gen)
+    t_end = time.perf_counter()
+    pre_s, dec_s = t_first[0] - t0, t_end - t_first[0]
+    dec_tok = args.batch * (args.gen - 1)
+    print(f"prefill: {args.batch * args.prompt_len} prompt tokens in "
+          f"{pre_s:.2f}s ({args.batch * args.prompt_len / pre_s:.1f} tok/s); "
+          f"decode: {dec_tok} more tokens in {dec_s:.2f}s "
+          f"({dec_tok / dec_s if dec_tok else 0.0:.1f} tok/s); "
+          f"compile excluded")
     print("sample:", toks[0][:12].tolist())
     return toks
 

@@ -409,10 +409,11 @@ def gqa_decode(params, cfg: ModelConfig, x, cache_k, cache_v, pos, impl="chunked
     b = x.shape[0]
     positions = jnp.full((b, 1), pos, jnp.int32)
     q, k, v = gqa_project_qkv(params, cfg, x, positions)
-    cache_k = jax.lax.dynamic_update_slice(
-        cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(
-        cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
+    with jax.named_scope("cache_update"):
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
     out = decode_attention(q, cache_k, cache_v, kv_len=pos + 1)
     out = jnp.einsum("bse,ed->bsd", out.reshape(b, 1, -1), params["wo"])
     return out, cache_k, cache_v
@@ -482,10 +483,11 @@ def mla_decode(params, cfg: ModelConfig, x, cache_ckv, cache_krope, pos):
     c_new, krope_new = ckv_full[..., :kvl], ckv_full[..., kvl:]
     krope_new = apply_rope(krope_new[:, :, None, :], positions,
                            cfg.rope_theta)[:, :, 0]
-    cache_ckv = jax.lax.dynamic_update_slice(
-        cache_ckv, c_new.astype(cache_ckv.dtype), (0, pos, 0))
-    cache_krope = jax.lax.dynamic_update_slice(
-        cache_krope, krope_new.astype(cache_krope.dtype), (0, pos, 0))
+    with jax.named_scope("cache_update"):
+        cache_ckv = jax.lax.dynamic_update_slice(
+            cache_ckv, c_new.astype(cache_ckv.dtype), (0, pos, 0))
+        cache_krope = jax.lax.dynamic_update_slice(
+            cache_krope, krope_new.astype(cache_krope.dtype), (0, pos, 0))
 
     q = jnp.einsum("bsd,dl->bsl", x, params["wq_a"])
     q = jnp.einsum("bsl,le->bse", q, params["wq_b"]).reshape(b, 1, h, hd + r)

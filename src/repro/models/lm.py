@@ -257,9 +257,24 @@ class LanguageModel:
     # ------------------------------------------------------------ decode step --
     def decode_step(self, params, cache, tokens, pos):
         """tokens: (B,1) int32; pos: scalar int32 (current length).
-        Returns (logits (B,1,V), new_cache)."""
+        Returns (logits (B,1,V), new_cache).
+
+        Named scopes give each part of the step an owner in the compiled
+        program's op metadata, and so in a device profile: ``embed``;
+        ``layers``, the scan over the layer stack with its slicing and carry
+        of the stacked cache, and inside it ``attention`` (with its
+        ``cache_update``) and ``ffn``; ``logits_sample``, the final norm and
+        head (and the sampling in ``serve/step.py``)."""
+        with jax.named_scope("embed"):
+            x = embed(params["emb"], tokens)
+        with jax.named_scope("layers"):
+            x, cache = self._decode_layers(params, cache, x, pos)
+        with jax.named_scope("logits_sample"):
+            h = rmsnorm(params["ln_f"], x, self.cfg.norm_eps)
+            return logits_for_tokens(params["emb"], h), cache
+
+    def _decode_layers(self, params, cache, x, pos):
         cfg = self.cfg
-        x = embed(params["emb"], tokens)
         b = x.shape[0]
 
         if cfg.family in ("dense", "vlm", "moe"):
@@ -267,17 +282,19 @@ class LanguageModel:
                 def body(x_, xs):
                     p_, ckv, krope = xs
                     from repro.models.attention import mla_decode
-                    h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
-                    o, ckv, krope = mla_decode(p_["attn"], cfg, h, ckv, krope, pos)
-                    x_ = x_ + o
-                    h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
-                    if "ffn" in p_:
-                        from repro.models.layers import ffn
-                        x_ = x_ + ffn(p_["ffn"], h)
-                    else:
-                        from repro.models.moe import moe_ffn
-                        y, _ = moe_ffn(p_["moe"], cfg, h)
-                        x_ = x_ + y
+                    with jax.named_scope("attention"):
+                        h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
+                        o, ckv, krope = mla_decode(p_["attn"], cfg, h, ckv, krope, pos)
+                        x_ = x_ + o
+                    with jax.named_scope("ffn"):
+                        h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
+                        if "ffn" in p_:
+                            from repro.models.layers import ffn
+                            x_ = x_ + ffn(p_["ffn"], h)
+                        else:
+                            from repro.models.moe import moe_ffn
+                            y, _ = moe_ffn(p_["moe"], cfg, h)
+                            x_ = x_ + y
                     return x_, (ckv, krope)
 
                 groups = []
@@ -300,16 +317,18 @@ class LanguageModel:
 
                 def body(x_, xs):
                     p_, k_, v_ = xs
-                    h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
-                    o, k_, v_ = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
-                    x_ = x_ + o
-                    h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
-                    if "ffn" in p_:
-                        x_ = x_ + ffn_fn(p_["ffn"], h)
-                    else:
-                        from repro.models.moe import moe_ffn
-                        y, _ = moe_ffn(p_["moe"], cfg, h)
-                        x_ = x_ + y
+                    with jax.named_scope("attention"):
+                        h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
+                        o, k_, v_ = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
+                        x_ = x_ + o
+                    with jax.named_scope("ffn"):
+                        h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
+                        if "ffn" in p_:
+                            x_ = x_ + ffn_fn(p_["ffn"], h)
+                        else:
+                            from repro.models.moe import moe_ffn
+                            y, _ = moe_ffn(p_["moe"], cfg, h)
+                            x_ = x_ + y
                     return x_, (k_, v_)
 
                 x, (k_new, v_new) = jax.lax.scan(
@@ -341,11 +360,13 @@ class LanguageModel:
                     from repro.models.layers import ffn as ffn_fn
                     k_i = jax.lax.dynamic_index_in_dim(sk_in, inv, 0, keepdims=False)
                     v_i = jax.lax.dynamic_index_in_dim(sv_in, inv, 0, keepdims=False)
-                    h = rmsnorm(shared["ln1"], x_in, cfg.norm_eps)
-                    o, k_i, v_i = gqa_decode(shared["attn"], cfg, h, k_i, v_i, pos)
-                    x2 = x_in + o
-                    h = rmsnorm(shared["ln2"], x2, cfg.norm_eps)
-                    x2 = x2 + ffn_fn(shared["ffn"], h)
+                    with jax.named_scope("attention"):
+                        h = rmsnorm(shared["ln1"], x_in, cfg.norm_eps)
+                        o, k_i, v_i = gqa_decode(shared["attn"], cfg, h, k_i, v_i, pos)
+                        x2 = x_in + o
+                    with jax.named_scope("ffn"):
+                        h = rmsnorm(shared["ln2"], x2, cfg.norm_eps)
+                        x2 = x2 + ffn_fn(shared["ffn"], h)
                     sk2 = jax.lax.dynamic_update_index_in_dim(sk_in, k_i, inv, 0)
                     sv2 = jax.lax.dynamic_update_index_in_dim(sv_in, v_i, inv, 0)
                     return x2, sk2, sv2
@@ -366,17 +387,19 @@ class LanguageModel:
 
             def body(x_, xs):
                 p_, k_, v_, ck, cv = xs
-                h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
-                o, k_, v_ = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
-                x_ = x_ + o
-                h = rmsnorm(p_["ln_cross"], x_, cfg.norm_eps)
-                q = jnp.einsum("bsd,de->bse", h, p_["cross"]["wq"]).reshape(
-                    b, 1, cfg.n_heads, cfg.head_dim)
-                o = decode_attention(q, ck, cv, kv_len=ck.shape[1])
-                x_ = x_ + jnp.einsum("bse,ed->bsd", o.reshape(b, 1, -1),
-                                     p_["cross"]["wo"])
-                h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
-                x_ = x_ + ffn_fn(p_["ffn"], h)
+                with jax.named_scope("attention"):
+                    h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
+                    o, k_, v_ = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
+                    x_ = x_ + o
+                    h = rmsnorm(p_["ln_cross"], x_, cfg.norm_eps)
+                    q = jnp.einsum("bsd,de->bse", h, p_["cross"]["wq"]).reshape(
+                        b, 1, cfg.n_heads, cfg.head_dim)
+                    o = decode_attention(q, ck, cv, kv_len=ck.shape[1])
+                    x_ = x_ + jnp.einsum("bse,ed->bsd", o.reshape(b, 1, -1),
+                                         p_["cross"]["wo"])
+                with jax.named_scope("ffn"):
+                    h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
+                    x_ = x_ + ffn_fn(p_["ffn"], h)
                 return x_, (k_, v_)
 
             x, (k_new, v_new) = jax.lax.scan(
@@ -385,6 +408,4 @@ class LanguageModel:
             cache = dict(cache, k=k_new, v=v_new)
         else:
             raise ValueError(cfg.family)
-
-        h = rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        return logits_for_tokens(params["emb"], h), cache
+        return x, cache

@@ -30,15 +30,16 @@ def make_decode_step(model, sample: str = "greedy", temperature: float = 1.0,
                      top_k: int = 0):
     def decode_step(params, cache, tokens, pos, rng):
         logits, cache = model.decode_step(params, cache, tokens, pos)
-        logits = logits[:, -1, :].astype(jnp.float32)
-        if sample == "greedy":
-            nxt = jnp.argmax(logits, axis=-1)
-        else:
-            logits = logits / jnp.maximum(temperature, 1e-6)
-            if top_k:
-                vals, _ = jax.lax.top_k(logits, top_k)
-                logits = jnp.where(logits < vals[:, -1:], -1e30, logits)
-            nxt = jax.random.categorical(rng, logits, axis=-1)
-        return nxt[:, None].astype(jnp.int32), cache
+        with jax.named_scope("logits_sample"):
+            logits = logits[:, -1, :].astype(jnp.float32)
+            if sample == "greedy":
+                nxt = jnp.argmax(logits, axis=-1)
+            else:
+                logits = logits / jnp.maximum(temperature, 1e-6)
+                if top_k:
+                    vals, _ = jax.lax.top_k(logits, top_k)
+                    logits = jnp.where(logits < vals[:, -1:], -1e30, logits)
+                nxt = jax.random.categorical(rng, logits, axis=-1)
+            return nxt[:, None].astype(jnp.int32), cache
 
     return decode_step

@@ -10,7 +10,17 @@ import repro.configs as C
 _POD_LARGE = {"qwen3-moe-235b-a22b", "deepseek-v2-236b"}
 
 
-def test_train_then_serve_roundtrip(tmp_path):
+@pytest.fixture
+def scoped_mesh():
+    """The launcher sets a process-wide mesh; the caller's comes back after
+    the test, so later tests on this worker do not compile under it."""
+    from repro.launch.mesh import make_host_mesh
+
+    with jax.sharding.set_mesh(make_host_mesh()):
+        yield
+
+
+def test_train_then_serve_roundtrip(tmp_path, scoped_mesh):
     """Train a tiny model a few steps, checkpoint, restore, serve tokens."""
     from repro.launch.train import main as train_main
     from repro.checkpoint.ckpt import restore
