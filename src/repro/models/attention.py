@@ -320,28 +320,47 @@ def flash_attention(q, k, v, *, causal: bool, scale: float | None = None,
     return out[:, :sq]
 
 
-def decode_attention(q, k_cache, v_cache, kv_len, scale: float | None = None):
+def decode_attention(q, k_cache, v_cache, kv_len, scale: float | None = None,
+                     k_new=None, v_new=None):
     """Single-token attention against a (possibly sequence-sharded) cache.
 
-    q: (B,1,H,D); caches: (B,S,KVH,D); kv_len: number of valid entries.
-    Score/softmax reductions over the cache axis lower to psum-style
-    collectives when S is sharded (context-parallel flash-decode).
+    q: (B,1,H,D); caches: (B,KVH,S,D), head-major; kv_len: number of valid
+    entries. ``k_new``/``v_new`` (B,KVH,1,D), where given, are the token's
+    own key and value, kept out of the cache: the token attends to the
+    cache's first ``kv_len`` positions and to its own key and value under
+    one softmax, so the cache is only read. Score/softmax reductions over
+    the cache axis lower to psum-style collectives when S is sharded
+    (context-parallel flash-decode).
     """
     b, _, h, d = q.shape
-    _, s, kvh, _ = k_cache.shape
+    _, kvh, s, _ = k_cache.shape
     g = h // kvh
     scale = scale if scale is not None else d ** -0.5
     qg = q.reshape(b, kvh, g, d)
-    scores = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache).astype(jnp.float32)
+    scores = jnp.einsum("bhgd,bhkd->bhgk", qg, k_cache).astype(jnp.float32)
     scores = scores * scale
     mask = jnp.arange(s)[None, None, None, :] < kv_len
     scores = jnp.where(mask, scores, NEG_INF)
     # int8-quantized caches: compute the weighted sum in bf16 (dequant is a
     # scale-fold upstream; the cast here keeps softmax weights non-integer)
     acc_dtype = jnp.bfloat16 if v_cache.dtype == jnp.int8 else v_cache.dtype
-    p = jax.nn.softmax(scores, axis=-1).astype(acc_dtype)
-    out = jnp.einsum("bhgk,bkhd->bhgd", p, v_cache.astype(acc_dtype))
-    return out.reshape(b, 1, h, v_cache.shape[-1])
+    if k_new is None:
+        p = jax.nn.softmax(scores, axis=-1).astype(acc_dtype)
+        out = jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(acc_dtype))
+        return out.reshape(b, 1, h, v_cache.shape[-1])
+    # two blocks of one online softmax: the cache's scores and the token's
+    # own, under a shared max and one denominator
+    s_new = jnp.einsum("bhgd,bhd->bhg", qg, k_new[:, :, 0]).astype(jnp.float32)
+    s_new = s_new * scale
+    m = jnp.maximum(scores.max(axis=-1), s_new)
+    e = jnp.exp(scores - m[..., None])
+    e_new = jnp.exp(s_new - m)
+    denom = e.sum(axis=-1) + e_new
+    out = jnp.einsum("bhgk,bhkd->bhgd", (e / denom[..., None]).astype(acc_dtype),
+                     v_cache.astype(acc_dtype),
+                     preferred_element_type=jnp.float32)
+    out = out + (e_new / denom)[..., None] * v_new.astype(jnp.float32)
+    return out.astype(acc_dtype).reshape(b, 1, h, v_cache.shape[-1])
 
 
 def sdpa(q, k, v, *, causal: bool, impl: str = "chunked",
@@ -403,20 +422,22 @@ def gqa_attention(params, cfg: ModelConfig, x, positions, *, causal=True,
     return jnp.einsum("bse,ed->bsd", out.reshape(b, s, -1), params["wo"])
 
 
-def gqa_decode(params, cfg: ModelConfig, x, cache_k, cache_v, pos, impl="chunked"):
-    """One-token decode. cache_[kv]: (B, S, KVH, D); pos: scalar index of the
-    new token. Returns (out, new_k, new_v)."""
+def gqa_decode(params, cfg: ModelConfig, x, cache_k, cache_v, pos):
+    """One-token decode against one layer's cache, which it reads where it
+    lies and never writes. cache_[kv]: (B, KVH, S, D); pos: scalar index of
+    the new token, which attends to the cache's positions before ``pos`` and
+    to its own key and value. Returns (out, k_row, v_row): the new token's
+    rows (B, KVH, 1, D) in the cache's dtype, for the caller to write at
+    ``pos`` under its ``cache_update`` scope."""
     b = x.shape[0]
     positions = jnp.full((b, 1), pos, jnp.int32)
     q, k, v = gqa_project_qkv(params, cfg, x, positions)
-    with jax.named_scope("cache_update"):
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
-    out = decode_attention(q, cache_k, cache_v, kv_len=pos + 1)
+    k_row = jnp.swapaxes(k, 1, 2).astype(cache_k.dtype)
+    v_row = jnp.swapaxes(v, 1, 2).astype(cache_v.dtype)
+    out = decode_attention(q, cache_k, cache_v, kv_len=pos,
+                           k_new=k_row, v_new=v_row)
     out = jnp.einsum("bse,ed->bsd", out.reshape(b, 1, -1), params["wo"])
-    return out, cache_k, cache_v
+    return out, k_row, v_row
 
 
 # --------------------------------------------------------------------------------

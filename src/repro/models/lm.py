@@ -34,6 +34,16 @@ REMAT_POLICIES = {
     "full": lambda: jax.checkpoint_policies.nothing_saveable,
 }
 
+# The decode step's new key and value rows leave the layer scan, and are
+# written into the cache, widened to float32. XLA:CPU has no bf16
+# dynamic-update-slice: it widens one itself, behind conversions that carry no
+# op metadata, and the decode step's scope map (benchmarks/chip/scopes.py)
+# would then place the writes by an operand instead of by their scope. Widened
+# here, the writes keep their scopes on every backend. The values are exact in
+# float32, and XLA:TPU folds the round trip into a write in the cache's own
+# dtype, in place.
+ROW_DTYPE = jnp.float32
+
 
 def _maybe_remat(fn, remat: str):
     if remat == "none":
@@ -202,6 +212,12 @@ class LanguageModel:
     # ------------------------------------------------------------------ cache --
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16,
                    enc_len: int = 0):
+        """Zeroed decode caches, stacked over layers. Attention caches are
+        head-major, (layers, batch, kv_heads, positions, head_dim): each
+        head's keys and values are one (positions, head_dim) matrix, the
+        operand that the decode step's per-head matmuls read where it lies.
+        (With positions before heads, a TPU copies every layer's slice to
+        transpose it, at every step.)"""
         cfg = self.cfg
         L = cfg.n_layers
         if cfg.family in ("dense", "vlm"):
@@ -212,8 +228,8 @@ class LanguageModel:
                 }
             kvh, hd = cfg.n_kv_heads, cfg.head_dim
             return {
-                "k": jnp.zeros((L, batch, max_len, kvh, hd), dtype),
-                "v": jnp.zeros((L, batch, max_len, kvh, hd), dtype),
+                "k": jnp.zeros((L, batch, kvh, max_len, hd), dtype),
+                "v": jnp.zeros((L, batch, kvh, max_len, hd), dtype),
             }
         if cfg.family == "moe":
             kd = cfg.first_k_dense
@@ -223,8 +239,8 @@ class LanguageModel:
                 base["krope"] = jnp.zeros((L, batch, max_len, cfg.rope_head_dim), dtype)
             else:
                 kvh, hd = cfg.n_kv_heads, cfg.head_dim
-                base["k"] = jnp.zeros((L, batch, max_len, kvh, hd), dtype)
-                base["v"] = jnp.zeros((L, batch, max_len, kvh, hd), dtype)
+                base["k"] = jnp.zeros((L, batch, kvh, max_len, hd), dtype)
+                base["v"] = jnp.zeros((L, batch, kvh, max_len, hd), dtype)
             return base
         if cfg.family == "ssm":
             return self._ssm_cache(batch, dtype)
@@ -232,16 +248,16 @@ class LanguageModel:
             cache = self._ssm_cache(batch, dtype)
             n_inv = cfg.n_layers // cfg.attn_every
             kvh, hd = cfg.n_kv_heads, cfg.head_dim
-            cache["shared_k"] = jnp.zeros((n_inv, batch, max_len, kvh, hd), dtype)
-            cache["shared_v"] = jnp.zeros((n_inv, batch, max_len, kvh, hd), dtype)
+            cache["shared_k"] = jnp.zeros((n_inv, batch, kvh, max_len, hd), dtype)
+            cache["shared_v"] = jnp.zeros((n_inv, batch, kvh, max_len, hd), dtype)
             return cache
         if cfg.family == "audio":
             kvh, hd = cfg.n_kv_heads, cfg.head_dim
             return {
-                "k": jnp.zeros((L, batch, max_len, kvh, hd), dtype),
-                "v": jnp.zeros((L, batch, max_len, kvh, hd), dtype),
-                "cross_k": jnp.zeros((L, batch, enc_len, kvh, hd), dtype),
-                "cross_v": jnp.zeros((L, batch, enc_len, kvh, hd), dtype),
+                "k": jnp.zeros((L, batch, kvh, max_len, hd), dtype),
+                "v": jnp.zeros((L, batch, kvh, max_len, hd), dtype),
+                "cross_k": jnp.zeros((L, batch, kvh, enc_len, hd), dtype),
+                "cross_v": jnp.zeros((L, batch, kvh, enc_len, hd), dtype),
             }
         raise ValueError(cfg.family)
 
@@ -261,10 +277,11 @@ class LanguageModel:
 
         Named scopes give each part of the step an owner in the compiled
         program's op metadata, and so in a device profile: ``embed``;
-        ``layers``, the scan over the layer stack with its slicing and carry
-        of the stacked cache, and inside it ``attention`` (with its
-        ``cache_update``) and ``ffn``; ``logits_sample``, the final norm and
-        head (and the sampling in ``serve/step.py``)."""
+        ``layers``, the scan over the layer stack, and inside it
+        ``attention`` (which reads the stacked KV cache where it lies) and
+        ``ffn``, then ``cache_update``, the one write of every layer's new
+        key and value rows at ``pos`` after the scan; ``logits_sample``, the
+        final norm and head (and the sampling in ``serve/step.py``)."""
         with jax.named_scope("embed"):
             x = embed(params["emb"], tokens)
         with jax.named_scope("layers"):
@@ -319,7 +336,7 @@ class LanguageModel:
                     p_, k_, v_ = xs
                     with jax.named_scope("attention"):
                         h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
-                        o, k_, v_ = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
+                        o, k_row, v_row = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
                         x_ = x_ + o
                     with jax.named_scope("ffn"):
                         h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
@@ -329,11 +346,11 @@ class LanguageModel:
                             from repro.models.moe import moe_ffn
                             y, _ = moe_ffn(p_["moe"], cfg, h)
                             x_ = x_ + y
-                    return x_, (k_, v_)
+                    return x_, (k_row.astype(ROW_DTYPE), v_row.astype(ROW_DTYPE))
 
-                x, (k_new, v_new) = jax.lax.scan(
+                x, (k_rows, v_rows) = jax.lax.scan(
                     body, x, (params["layers"], cache["k"], cache["v"]))
-                cache = {"k": k_new, "v": v_new}
+                cache = _write_kv_rows(cache, k_rows, v_rows, pos)
         elif cfg.family == "ssm":
             def body(x_, xs):
                 p_, cs, ss = xs
@@ -362,13 +379,15 @@ class LanguageModel:
                     v_i = jax.lax.dynamic_index_in_dim(sv_in, inv, 0, keepdims=False)
                     with jax.named_scope("attention"):
                         h = rmsnorm(shared["ln1"], x_in, cfg.norm_eps)
-                        o, k_i, v_i = gqa_decode(shared["attn"], cfg, h, k_i, v_i, pos)
+                        o, k_row, v_row = gqa_decode(shared["attn"], cfg, h, k_i, v_i, pos)
                         x2 = x_in + o
                     with jax.named_scope("ffn"):
                         h = rmsnorm(shared["ln2"], x2, cfg.norm_eps)
                         x2 = x2 + ffn_fn(shared["ffn"], h)
-                    sk2 = jax.lax.dynamic_update_index_in_dim(sk_in, k_i, inv, 0)
-                    sv2 = jax.lax.dynamic_update_index_in_dim(sv_in, v_i, inv, 0)
+                    with jax.named_scope("cache_update"):
+                        at = (inv, 0, 0, pos, 0)
+                        sk2 = jax.lax.dynamic_update_slice(sk_in, k_row[None], at)
+                        sv2 = jax.lax.dynamic_update_slice(sv_in, v_row[None], at)
                     return x2, sk2, sv2
 
                 x_, sk_, sv_ = jax.lax.cond(
@@ -389,23 +408,35 @@ class LanguageModel:
                 p_, k_, v_, ck, cv = xs
                 with jax.named_scope("attention"):
                     h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
-                    o, k_, v_ = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
+                    o, k_row, v_row = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
                     x_ = x_ + o
                     h = rmsnorm(p_["ln_cross"], x_, cfg.norm_eps)
                     q = jnp.einsum("bsd,de->bse", h, p_["cross"]["wq"]).reshape(
                         b, 1, cfg.n_heads, cfg.head_dim)
-                    o = decode_attention(q, ck, cv, kv_len=ck.shape[1])
+                    o = decode_attention(q, ck, cv, kv_len=ck.shape[2])
                     x_ = x_ + jnp.einsum("bse,ed->bsd", o.reshape(b, 1, -1),
                                          p_["cross"]["wo"])
                 with jax.named_scope("ffn"):
                     h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
                     x_ = x_ + ffn_fn(p_["ffn"], h)
-                return x_, (k_, v_)
+                return x_, (k_row.astype(ROW_DTYPE), v_row.astype(ROW_DTYPE))
 
-            x, (k_new, v_new) = jax.lax.scan(
+            x, (k_rows, v_rows) = jax.lax.scan(
                 body, x, (params["layers"], cache["k"], cache["v"],
                           cache["cross_k"], cache["cross_v"]))
-            cache = dict(cache, k=k_new, v=v_new)
+            cache = _write_kv_rows(cache, k_rows, v_rows, pos)
         else:
             raise ValueError(cfg.family)
         return x, cache
+
+
+def _write_kv_rows(cache, k_rows, v_rows, pos):
+    """Write every layer's new key and value rows (L, B, KVH, 1, D), stacked
+    by the layer scan, into the stacked cache at ``pos``: one write each for
+    K and V, in place on a donated cache, after the scan has read it."""
+    with jax.named_scope("cache_update"):
+        at = (0, 0, 0, pos, 0)
+        return dict(cache, **{
+            n: jax.lax.dynamic_update_slice(
+                cache[n].astype(ROW_DTYPE), rows, at).astype(cache[n].dtype)
+            for n, rows in (("k", k_rows), ("v", v_rows))})

@@ -97,37 +97,40 @@ def batch_spec(mesh: Mesh, seq_sharded: bool = False) -> PartitionSpec:
 def cache_shardings(cache_tree, mesh: Mesh, shard_seq: bool = False):
     """KV-cache shardings. Layout per family (leading dim = layers):
 
-    attention k/v (L, B, S, KVH, D): batch over (pod,data); kv-heads over
-    model when divisible, otherwise the SEQUENCE dim shards over model —
-    decode is bandwidth-bound, so spreading the cache across chips buys
-    aggregate HBM bandwidth (the COPA 'compose more memory system around
-    fixed compute' move); XLA turns the softmax reductions into psums.
-    MLA latent caches (no head dim) always sequence-shard. ``shard_seq``
-    (gb=1 long-context) shards S over data instead. SSM conv/ssm states:
-    batch over (pod,data)."""
+    attention k/v (L, B, KVH, S, D), head-major: batch over (pod,data);
+    kv-heads over model when divisible, otherwise the SEQUENCE dim shards
+    over model — decode is bandwidth-bound, so spreading the cache across
+    chips buys aggregate HBM bandwidth (the COPA 'compose more memory system
+    around fixed compute' move); XLA turns the softmax reductions into psums.
+    MLA latent caches (L, B, S, r) (no head dim) always sequence-shard.
+    ``shard_seq`` (gb=1 long-context) shards S over data instead. SSM
+    conv/ssm states: batch over (pod,data)."""
     dims = {a: s for a, s in zip(mesh.axis_names, mesh.devices.shape)}
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     bspec = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
     nbatch = max(_flat(dims, batch_axes), 1)
 
+    def model_ok(n: int) -> bool:
+        return n % dims.get("model", 1) == 0
+
     def spec_for(name: str, arr) -> PartitionSpec:
         shape = arr.shape
         if name in ("k", "v", "shared_k", "shared_v", "cross_k", "cross_v",
                     "ckv", "krope"):
-            seq_ok_model = shape[2] % dims.get("model", 1) == 0
-            if shard_seq and shape[2] % dims.get("data", 1) == 0:
-                return PartitionSpec(None, None, "data")
-            if shape[1] % nbatch == 0 and shape[1] > 1:
-                entries = [None, bspec, None]
-                has_kvh = name not in ("ckv", "krope") and len(shape) >= 4
-                if has_kvh and shape[3] % dims.get("model", 1) == 0:
-                    entries += ["model"]
-                elif seq_ok_model:
-                    entries[2] = "model"   # context-parallel over TP axis
-                return PartitionSpec(*entries)
-            if seq_ok_model:
-                return PartitionSpec(None, None, "model")
-            return PartitionSpec()
+            latent = name in ("ckv", "krope")
+            seq = 2 if latent else 3
+            spec = [None] * (seq + 1)
+            if shard_seq and shape[seq] % dims.get("data", 1) == 0:
+                spec[seq] = "data"
+            elif shape[1] % nbatch == 0 and shape[1] > 1:
+                spec[1] = bspec
+                if not latent and model_ok(shape[2]):
+                    spec[2] = "model"
+                elif model_ok(shape[seq]):
+                    spec[seq] = "model"   # context-parallel over TP axis
+            elif model_ok(shape[seq]):
+                spec[seq] = "model"
+            return PartitionSpec(*spec)
         # ssm conv/ssm states: (L, B, ...)
         if shape[1] % nbatch == 0 and shape[1] > 1:
             return PartitionSpec(None, bspec)

@@ -86,7 +86,8 @@ def test_decode_attention_matches_naive():
     k = jax.random.normal(ks[1], (b, s, kvh, d), jnp.float32)
     v = jax.random.normal(ks[2], (b, s, kvh, d), jnp.float32)
     kv_len = 40
-    got = decode_attention(q, k, v, kv_len=kv_len)
+    # the decode cache is head-major, (B, KVH, S, D)
+    got = decode_attention(q, k.swapaxes(1, 2), v.swapaxes(1, 2), kv_len=kv_len)
     want = naive_attention(q, k, v, causal=False, kv_len=kv_len)
     np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
 

@@ -13,8 +13,10 @@ the library.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -84,24 +86,72 @@ def test_kernel_compiles_at_real_width(one_chip, kernel):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_granite_decode_step_fits_one_chip(one_chip):
-    """The whole serving decode step of granite-3-2b at its published
-    widths, all 40 layers, batch 8 against a 4096-token cache."""
-    import repro.configs as configs
+def _compile_decode_step(sharding, cfg, batch: int, max_len: int):
+    """The serving decode step compiled as the engine compiles it (cache
+    donated), from shapes alone; returns (compiled, cache shapes)."""
     from repro.models import LanguageModel
     from repro.models.base import abstract_params
     from repro.serve.step import make_decode_step
 
-    model = LanguageModel(configs.get("granite-3-2b"))
-    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+    model = LanguageModel(cfg)
+    params = jax.tree.map(lambda a: _sds(sharding, a.shape, a.dtype),
                           abstract_params(model.specs()))
-    cache = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
-                         jax.eval_shape(lambda: model.init_cache(8, 4096)))
+    cache = jax.tree.map(lambda a: _sds(sharding, a.shape, a.dtype),
+                         jax.eval_shape(lambda: model.init_cache(batch, max_len)))
     compiled = jax.jit(make_decode_step(model), donate_argnums=(1,)).lower(
-        params, cache, _sds(one_chip, (8, 1), jnp.int32),
-        _sds(one_chip, (), jnp.int32),
-        _sds(one_chip, (2,), jnp.uint32)).compile()
+        params, cache, _sds(sharding, (batch, 1), jnp.int32),
+        _sds(sharding, (), jnp.int32),
+        _sds(sharding, (2,), jnp.uint32)).compile()
+    return compiled, cache
+
+
+def test_granite_decode_step_fits_one_chip(one_chip):
+    """The whole serving decode step of granite-3-2b at its published
+    widths, all 40 layers, batch 8 against a 4096-token cache."""
+    import repro.configs as configs
+
+    compiled, _ = _compile_decode_step(one_chip, configs.get("granite-3-2b"),
+                                       8, 4096)
     mem = compiled.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert peak < HBM_LIMIT, peak / 2 ** 30
+
+
+def _materialized(hlo: str, shapes) -> list[str]:
+    """Instructions outside fused computations whose result is an array of
+    one of ``shapes``, leaving out parameters, tuple plumbing, bitcasts and
+    dynamic-update-slices (written in place)."""
+    dims = "|".join(",".join(map(str, s)) for s in shapes)
+    result = re.compile(rf"^\s*(ROOT )?%\S+ = \(?\w+\[({dims})\]")
+    keep = re.compile(r" (parameter|get-tuple-element|tuple|bitcast|"
+                      r"dynamic-update-slice)\(")
+    found, fused = [], False
+    for line in hlo.splitlines():
+        if not line.startswith(" "):
+            fused = line.startswith("%fused")
+        elif not fused and result.match(line) and not keep.search(line):
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("arch, n_layers, batch, max_len", [
+    ("granite-3-2b", 40, 32, 1024),        # the chat cell's shapes
+    ("mistral-nemo-12b", 10, 16, 4096),    # one pipeline stage, reasoning's
+])
+def test_decode_step_writes_only_new_rows(one_chip, arch, n_layers, batch,
+                                          max_len):
+    """The layers read the stacked KV cache where it lies and the step
+    writes only the new token's rows into the donated cache: no temporary
+    near the cache's size, and no op that copies the whole stacked cache or
+    one layer's slice of it."""
+    import repro.configs as configs
+
+    cfg = dataclasses.replace(configs.get(arch), n_layers=n_layers)
+    compiled, cache = _compile_decode_step(one_chip, cfg, batch, max_len)
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < cache_bytes / 8, (temp / 2 ** 30, cache_bytes / 2 ** 30)
+    stack = cache["k"].shape
+    assert not _materialized(compiled.as_text(),
+                             [stack, (1, *stack[1:]), stack[1:]])
