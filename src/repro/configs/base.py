@@ -30,7 +30,15 @@ class ModelConfig:
     moe_d_ff: int = 0           # per-expert hidden dim
     first_k_dense: int = 0      # leading dense layers (DeepSeek)
     dense_d_ff: int = 0         # hidden dim of those dense layers
-    capacity_factor: float = 1.25
+    n_group: int = 1            # expert groups; route within the best topk_group
+    topk_group: int = 1
+    norm_topk_prob: bool = True  # renormalise the top-k weights to sum to 1
+    routed_scaling_factor: float = 1.0  # multiplies unrenormalised weights
+    # This chip's share under expert parallelism: experts
+    # [experts_first, experts_first + experts_held) of every MoE layer;
+    # experts_held 0 holds all of them.
+    experts_first: int = 0
+    experts_held: int = 0
 
     # --- MLA (DeepSeek-V2) ---
     use_mla: bool = False
@@ -38,6 +46,13 @@ class ModelConfig:
     q_lora_rank: int = 0
     rope_head_dim: int = 64
     v_head_dim: int = 0         # 0 -> head_dim
+    # YaRN rope scaling of the MLA rope dims (yarn_factor 1: plain rope)
+    yarn_factor: float = 1.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- SSM (Mamba-2 / SSD) ---
     ssm_state: int = 0
@@ -63,6 +78,17 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.use_mla and self.v_head_dim == 0:
             object.__setattr__(self, "v_head_dim", self.head_dim)
+        if self.n_experts:
+            if self.experts_held == 0:
+                object.__setattr__(self, "experts_held", self.n_experts)
+            if (self.experts_first < 0 or self.experts_first + self.experts_held
+                    > self.n_experts or self.n_experts % self.n_group
+                    or self.topk_group > self.n_group):
+                raise ValueError(
+                    f"{self.name}: experts [{self.experts_first}, "
+                    f"{self.experts_first + self.experts_held}) of "
+                    f"{self.n_experts} in {self.n_group} groups, top "
+                    f"{self.topk_group} groups")
 
     # ---- derived sizes --------------------------------------------------------
     @property
@@ -93,7 +119,8 @@ class ModelConfig:
             q = self.q_lora_rank * d + self.q_lora_rank * h * (hd + r) if self.q_lora_rank else d * h * (hd + r)
             kvp = d * (self.kv_lora_rank + r) + self.kv_lora_rank * h * (hd + self.v_head_dim)
             o = h * self.v_head_dim * d
-            return q + kvp + o
+            norms = self.q_lora_rank + self.kv_lora_rank  # q_a, kv_a layernorms
+            return q + kvp + o + norms
         return d * h * hd + 2 * d * kv * hd + h * hd * d
 
     def ffn_params(self, d_ff: int) -> int:
@@ -124,7 +151,7 @@ class ModelConfig:
         if self.n_experts:
             moe_layers = dec - self.first_k_dense
             dense_layers = self.first_k_dense
-            expert_p = (self.n_experts + self.n_shared_experts) * self.ffn_params(self.moe_d_ff)
+            expert_p = (self.experts_held + self.n_shared_experts) * self.ffn_params(self.moe_d_ff)
             router_p = self.d_model * self.n_experts
             total += dec * per_layer_attn
             total += moe_layers * (expert_p + router_p)
@@ -165,7 +192,10 @@ class ModelConfig:
         if self.n_experts:
             kw.update(n_experts=4, top_k=2, moe_d_ff=64,
                       n_shared_experts=min(self.n_shared_experts, 1),
-                      first_k_dense=min(self.first_k_dense, 1), dense_d_ff=128)
+                      first_k_dense=min(self.first_k_dense, 1), dense_d_ff=128,
+                      experts_first=0, experts_held=0)
+            if self.n_group > 1:   # group-limited routing: 4 groups of 2, top 2
+                kw.update(n_experts=8, n_group=4, topk_group=2, top_k=3)
         if self.use_mla:
             kw.update(kv_lora_rank=32, q_lora_rank=48, rope_head_dim=8, v_head_dim=16)
         if self.ssm_state:

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.base import P, Specs
-from repro.models.layers import apply_rope
+from repro.models.layers import apply_rope, rmsnorm, yarn_frequencies, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -450,82 +450,127 @@ def mla_specs(cfg: ModelConfig) -> Specs:
     ql, kvl = cfg.q_lora_rank, cfg.kv_lora_rank
     return {
         "wq_a": P((d, ql), ("embed", "lora")),
+        "wq_a_norm": {"scale": P((ql,), ("lora",), init="ones")},
         "wq_b": P((ql, h * (hd + r)), ("lora", "heads")),
         "wkv_a": P((d, kvl + r), ("embed", "lora")),
+        "wkv_a_norm": {"scale": P((kvl,), ("lora",), init="ones")},
         "wk_b": P((kvl, h * hd), ("lora", "heads")),
         "wv_b": P((kvl, h * vd), ("lora", "heads")),
         "wo": P((h * vd, d), ("heads", "embed")),
     }
 
 
-def _mla_qkv(params, cfg: ModelConfig, x, positions, c_kv, k_rope):
-    """Expand latent cache into per-head K/V and build rope-augmented Q/K."""
-    b, s_kv = c_kv.shape[0], c_kv.shape[1]
-    s_q = x.shape[1]
-    h, hd, r, vd = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim, cfg.v_head_dim
-    q = jnp.einsum("bsd,dl->bsl", x, params["wq_a"])
-    q = jnp.einsum("bsl,le->bse", q, params["wq_b"]).reshape(b, s_q, h, hd + r)
-    q_nope, q_rope = q[..., :hd], q[..., hd:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    k_nope = jnp.einsum("bsl,le->bse", c_kv, params["wk_b"]).reshape(b, s_kv, h, hd)
-    v = jnp.einsum("bsl,le->bse", c_kv, params["wv_b"]).reshape(b, s_kv, h, vd)
-    # shared rope key broadcast across heads
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, s_kv, h, r))], -1)
-    q_full = jnp.concatenate([q_nope, q_rope], -1)
-    return q_full, k, v
+def mla_rope(cfg: ModelConfig, x, positions):
+    """Rope on MLA's rope dims x (..., S, H, r), YaRN-scaled where the
+    configuration says. The published model rotates interleaved pairs
+    (2i, 2i+1) and returns them de-interleaved; so does this: the dims are
+    de-interleaved first, then rotated as halves."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    if cfg.yarn_factor <= 1.0:
+        return apply_rope(x, positions, cfg.rope_theta)
+    freqs = yarn_frequencies(x.shape[-1], cfg.rope_theta, cfg.yarn_factor,
+                             cfg.yarn_original_max_position,
+                             cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    out = apply_rope(x, positions, cfg.rope_theta, freqs)
+    m = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+         / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return out if m == 1.0 else (out * m).astype(out.dtype)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope) ** -0.5, times YaRN's mscale(factor,
+    mscale_all_dim) squared where the rope is YaRN-scaled."""
+    scale = (cfg.head_dim + cfg.rope_head_dim) ** -0.5
+    if cfg.yarn_factor > 1.0 and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_query(params, cfg: ModelConfig, x, positions):
+    """(q_nope (B,S,H,hd), q_rope (B,S,H,r)): the query through its
+    normalised latent."""
+    b, s, _ = x.shape
+    h, hd, r = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim
+    q = rmsnorm(params["wq_a_norm"], jnp.einsum("bsd,dl->bsl", x, params["wq_a"]),
+                cfg.norm_eps)
+    q = jnp.einsum("bsl,le->bse", q, params["wq_b"]).reshape(b, s, h, hd + r)
+    return q[..., :hd], mla_rope(cfg, q[..., hd:], positions)
+
+
+def _mla_latent(params, cfg: ModelConfig, x, positions):
+    """(c_kv (B,S,kvl), k_rope (B,S,r)): what the cache holds of each
+    token, the normalised KV latent and the shared rope key."""
+    kvl = cfg.kv_lora_rank
+    ckv = jnp.einsum("bsd,dl->bsl", x, params["wkv_a"])
+    c_kv = rmsnorm(params["wkv_a_norm"], ckv[..., :kvl], cfg.norm_eps)
+    k_rope = mla_rope(cfg, ckv[:, :, None, kvl:], positions)[:, :, 0]
+    return c_kv, k_rope
 
 
 def mla_attention(params, cfg: ModelConfig, x, positions, *, causal=True,
                   impl="chunked"):
+    """Whole-sequence MLA in the expanded form: per-head keys and values
+    rebuilt from the latent."""
     b, s, _ = x.shape
-    kvl, r = cfg.kv_lora_rank, cfg.rope_head_dim
-    ckv_full = jnp.einsum("bsd,dl->bsl", x, params["wkv_a"])
-    c_kv, k_rope = ckv_full[..., :kvl], ckv_full[..., kvl:]
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
-    q, k, v = _mla_qkv(params, cfg, x, positions, c_kv, k_rope)
-    scale = (cfg.head_dim + r) ** -0.5
-    out = sdpa(q, k, v, causal=causal, impl=impl, scale=scale,
+    h, hd, r, vd = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim, cfg.v_head_dim
+    q_nope, q_rope = _mla_query(params, cfg, x, positions)
+    c_kv, k_rope = _mla_latent(params, cfg, x, positions)
+    k_nope = jnp.einsum("bsl,le->bse", c_kv, params["wk_b"]).reshape(b, s, h, hd)
+    v = jnp.einsum("bsl,le->bse", c_kv, params["wv_b"]).reshape(b, s, h, vd)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, r))], -1)
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    out = sdpa(q, k, v, causal=causal, impl=impl, scale=mla_softmax_scale(cfg),
                positions=positions)
     return jnp.einsum("bse,ed->bsd", out.reshape(b, s, -1), params["wo"])
 
 
 def mla_decode(params, cfg: ModelConfig, x, cache_ckv, cache_krope, pos):
-    """One-token MLA decode in the ABSORBED form: scores are computed against
-    the latent cache directly (wk_b folded into q, wv_b applied after the
-    weighted latent sum), so per-head K/V are never expanded over the cache.
-    The cache stores only (kv_lora + rope) per token — the compressed cache
-    is itself a DRAM-traffic filter, exactly the paper's L3 argument."""
-    b = x.shape[0]
-    h, hd = cfg.n_heads, cfg.head_dim
-    kvl, r, vd = cfg.kv_lora_rank, cfg.rope_head_dim, cfg.v_head_dim
-    positions = jnp.full((b, 1), pos, jnp.int32)
-    ckv_full = jnp.einsum("bsd,dl->bsl", x, params["wkv_a"])
-    c_new, krope_new = ckv_full[..., :kvl], ckv_full[..., kvl:]
-    krope_new = apply_rope(krope_new[:, :, None, :], positions,
-                           cfg.rope_theta)[:, :, 0]
-    with jax.named_scope("cache_update"):
-        cache_ckv = jax.lax.dynamic_update_slice(
-            cache_ckv, c_new.astype(cache_ckv.dtype), (0, pos, 0))
-        cache_krope = jax.lax.dynamic_update_slice(
-            cache_krope, krope_new.astype(cache_krope.dtype), (0, pos, 0))
+    """One-token MLA decode in the ABSORBED form, against one layer's latent
+    cache, which it reads where it lies and never writes: wk_b is folded
+    into the query and wv_b applied after the weighted latent sum, so
+    per-head keys and values are never expanded over the cache. The cache
+    holds only (kv_lora + rope) per token, a DRAM-traffic filter exactly as
+    the paper's L3 argument has it.
 
-    q = jnp.einsum("bsd,dl->bsl", x, params["wq_a"])
-    q = jnp.einsum("bsl,le->bse", q, params["wq_b"]).reshape(b, 1, h, hd + r)
-    q_nope, q_rope = q[..., :hd], q[..., hd:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    wk_b = params["wk_b"].reshape(kvl, h, hd)
+    cache_ckv (B, S, kvl), cache_krope (B, S, r); pos: scalar index of the
+    new token, which attends to the cache's positions before ``pos`` and to
+    its own latent under one softmax. The contractions against the cache
+    take its operands as they lie (bfloat16) and accumulate in float32.
+    Returns (out, c_row, k_row): the token's rows (B, 1, kvl) and (B, 1, r)
+    in the cache's dtype, for the caller to write at ``pos``."""
+    b = x.shape[0]
+    h, vd, kvl = cfg.n_heads, cfg.v_head_dim, cfg.kv_lora_rank
+    dt = cache_ckv.dtype
+    positions = jnp.full((b, 1), pos, jnp.int32)
+    c_new, k_new = _mla_latent(params, cfg, x, positions)
+    c_row, k_row = c_new.astype(dt), k_new.astype(dt)
+    q_nope, q_rope = _mla_query(params, cfg, x, positions)
+    wk_b = params["wk_b"].reshape(kvl, h, cfg.head_dim)
+    q_abs = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0], wk_b).astype(dt)
+    q_rope = q_rope[:, 0].astype(dt)
+    scale = mla_softmax_scale(cfg)
+    f32 = jnp.float32
+    with jax.named_scope("latent_core"):
+        scores = (jnp.einsum("bhl,bkl->bhk", q_abs, cache_ckv,
+                             preferred_element_type=f32)
+                  + jnp.einsum("bhr,bkr->bhk", q_rope, cache_krope,
+                               preferred_element_type=f32)) * scale
+        mask = jnp.arange(cache_ckv.shape[1])[None, None, :] < pos
+        scores = jnp.where(mask, scores, NEG_INF)
+        s_new = (jnp.einsum("bhl,bl->bh", q_abs, c_row[:, 0],
+                            preferred_element_type=f32)
+                 + jnp.einsum("bhr,br->bh", q_rope, k_row[:, 0],
+                              preferred_element_type=f32)) * scale
+        # two blocks of one softmax: the cache's positions and the token's own
+        m = jnp.maximum(scores.max(axis=-1), s_new)
+        e = jnp.exp(scores - m[..., None])
+        e_new = jnp.exp(s_new - m)
+        denom = e.sum(axis=-1) + e_new
+        ctx = jnp.einsum("bhk,bkl->bhl", (e / denom[..., None]).astype(dt),
+                         cache_ckv, preferred_element_type=f32)
+        ctx = ctx + (e_new / denom)[..., None] * c_row.astype(f32)
     wv_b = params["wv_b"].reshape(kvl, h, vd)
-    q_abs = jnp.einsum("bqhd,lhd->bqhl", q_nope, wk_b)
-    s_nope = jnp.einsum("bqhl,bkl->bhqk", q_abs.astype(jnp.float32),
-                        cache_ckv.astype(jnp.float32))
-    s_rope = jnp.einsum("bqhr,bkr->bhqk", q_rope.astype(jnp.float32),
-                        cache_krope.astype(jnp.float32))
-    scores = (s_nope + s_rope) * ((hd + r) ** -0.5)
-    mask = jnp.arange(cache_ckv.shape[1])[None, None, None, :] < pos + 1
-    scores = jnp.where(mask, scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhqk,bkl->bqhl", p.astype(cache_ckv.dtype), cache_ckv)
-    out = jnp.einsum("bqhl,lhv->bqhv", ctx, wv_b)
-    out = jnp.einsum("bse,ed->bsd", out.reshape(b, 1, -1), params["wo"])
-    return out, cache_ckv, cache_krope
+    out = jnp.einsum("bhl,lhv->bhv", ctx.astype(x.dtype), wv_b)
+    out = jnp.einsum("be,ed->bd", out.reshape(b, h * vd), params["wo"])
+    return out[:, None], c_row, k_row

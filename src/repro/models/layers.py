@@ -2,6 +2,8 @@
 cross-entropy. Pure functions over param dicts (see ``models.base``)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -31,10 +33,41 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, 0.1 * mscale * ln(factor) + 1
+    (1 where the context is not stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float, original_max: int,
+                     beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN inverse frequencies (arXiv:2309.00071, as DeepSeek-V2 publishes
+    them): dimensions that turn more than ``beta_fast`` times over the
+    original context keep their frequency, those that turn fewer than
+    ``beta_slow`` times are divided by ``factor``, and a linear ramp joins
+    the two."""
+    def turns_dim(turns):
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_frequencies(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low),
+                    0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: jax.Array | None = None) -> jax.Array:
+    """x: (..., S, H, D); positions: broadcastable to (..., S). ``freqs``
+    (D/2,) replaces the plain ``rope_frequencies`` (YaRN)."""
     half = x.shape[-1] // 2
-    freqs = rope_frequencies(x.shape[-1], theta)            # (half,)
+    if freqs is None:
+        freqs = rope_frequencies(x.shape[-1], theta)        # (half,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, half)
     cos = jnp.cos(angles)[..., :, None, :]                  # (..., S, 1, half)
     sin = jnp.sin(angles)[..., :, None, :]

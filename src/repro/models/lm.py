@@ -296,38 +296,40 @@ class LanguageModel:
 
         if cfg.family in ("dense", "vlm", "moe"):
             if cfg.use_mla:
+                from repro.models.attention import mla_decode
+                from repro.models.layers import ffn as ffn_fn
+                from repro.models.moe import moe_ffn
+
                 def body(x_, xs):
-                    p_, ckv, krope = xs
-                    from repro.models.attention import mla_decode
+                    p_, i = xs
+                    ckv = jax.lax.dynamic_index_in_dim(cache["ckv"], i, 0, False)
+                    krope = jax.lax.dynamic_index_in_dim(cache["krope"], i, 0, False)
                     with jax.named_scope("attention"):
                         h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
-                        o, ckv, krope = mla_decode(p_["attn"], cfg, h, ckv, krope, pos)
+                        o, c_row, k_row = mla_decode(p_["attn"], cfg, h, ckv, krope, pos)
                         x_ = x_ + o
                     with jax.named_scope("ffn"):
                         h = rmsnorm(p_["ln2"], x_, cfg.norm_eps)
                         if "ffn" in p_:
-                            from repro.models.layers import ffn
-                            x_ = x_ + ffn(p_["ffn"], h)
+                            x_ = x_ + ffn_fn(p_["ffn"], h)
                         else:
-                            from repro.models.moe import moe_ffn
-                            y, _ = moe_ffn(p_["moe"], cfg, h)
-                            x_ = x_ + y
-                    return x_, (ckv, krope)
+                            x_ = x_ + moe_ffn(p_["moe"], cfg, h)[0]
+                    return x_, (c_row.astype(ROW_DTYPE), k_row.astype(ROW_DTYPE))
 
-                groups = []
-                if cfg.first_k_dense and "dense_layers" in params:
-                    groups.append(("dense_layers", cfg.first_k_dense, 0))
-                groups.append(("layers", cfg.n_layers - cfg.first_k_dense,
-                               cfg.first_k_dense))
-                new_ckv, new_krope = cache["ckv"], cache["krope"]
-                for pkey, n_l, off in groups:
-                    sl = lambda a: jax.lax.dynamic_slice_in_dim(a, off, n_l, 0)
-                    x, (ckv_g, krope_g) = jax.lax.scan(
-                        body, x,
-                        (params[pkey], sl(cache["ckv"]), sl(cache["krope"])))
-                    new_ckv = jax.lax.dynamic_update_slice_in_dim(new_ckv, ckv_g, off, 0)
-                    new_krope = jax.lax.dynamic_update_slice_in_dim(new_krope, krope_g, off, 0)
-                cache = {"ckv": new_ckv, "krope": new_krope}
+                # The leading dense layers and the MoE layers are two scans
+                # over one stacked latent cache; each reads its layers'
+                # slices where they lie, by index.
+                kd = cfg.first_k_dense
+                rows = []
+                for pkey, lo, hi in (("dense_layers", 0, kd),
+                                     ("layers", kd, cfg.n_layers)):
+                    if hi > lo:
+                        x, r = jax.lax.scan(
+                            body, x, (params[pkey], jnp.arange(lo, hi)))
+                        rows.append(r)
+                cache = _write_rows(cache, {
+                    n: jnp.concatenate([r[j] for r in rows])
+                    for j, n in enumerate(("ckv", "krope"))}, pos)
             else:
                 from repro.models.attention import gqa_decode
                 from repro.models.layers import ffn as ffn_fn
@@ -350,7 +352,7 @@ class LanguageModel:
 
                 x, (k_rows, v_rows) = jax.lax.scan(
                     body, x, (params["layers"], cache["k"], cache["v"]))
-                cache = _write_kv_rows(cache, k_rows, v_rows, pos)
+                cache = _write_rows(cache, {"k": k_rows, "v": v_rows}, pos)
         elif cfg.family == "ssm":
             def body(x_, xs):
                 p_, cs, ss = xs
@@ -424,19 +426,21 @@ class LanguageModel:
             x, (k_rows, v_rows) = jax.lax.scan(
                 body, x, (params["layers"], cache["k"], cache["v"],
                           cache["cross_k"], cache["cross_v"]))
-            cache = _write_kv_rows(cache, k_rows, v_rows, pos)
+            cache = _write_rows(cache, {"k": k_rows, "v": v_rows}, pos)
         else:
             raise ValueError(cfg.family)
         return x, cache
 
 
-def _write_kv_rows(cache, k_rows, v_rows, pos):
-    """Write every layer's new key and value rows (L, B, KVH, 1, D), stacked
-    by the layer scan, into the stacked cache at ``pos``: one write each for
-    K and V, in place on a donated cache, after the scan has read it."""
+def _write_rows(cache, rows, pos):
+    """Write every layer's new rows, stacked by the layer scan, into the
+    stacked cache at ``pos``: one write per cache array (positions on its
+    second-last axis: K and V (L, B, KVH, S, D), the latent caches (L, B,
+    S, r)), in place on a donated cache, after the scan has read it."""
     with jax.named_scope("cache_update"):
-        at = (0, 0, 0, pos, 0)
-        return dict(cache, **{
-            n: jax.lax.dynamic_update_slice(
-                cache[n].astype(ROW_DTYPE), rows, at).astype(cache[n].dtype)
-            for n, rows in (("k", k_rows), ("v", v_rows))})
+        out = dict(cache)
+        for n, r in rows.items():
+            at = (0,) * (r.ndim - 2) + (pos, 0)
+            out[n] = jax.lax.dynamic_update_slice(
+                cache[n].astype(ROW_DTYPE), r, at).astype(cache[n].dtype)
+        return out

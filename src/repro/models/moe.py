@@ -1,19 +1,24 @@
-"""Mixture-of-Experts: top-k routing with sort-based grouped dispatch.
+"""Mixture-of-Experts: routing over every expert, a dropless layer over the
+experts this chip holds.
 
-Design (TPU-native, compile-friendly at 128-160 experts):
+* Router (float32, as published): softmax scores over all ``n_experts``.
+  With ``n_group`` > 1, group-limited greedy (DeepSeek-V2): a group's score
+  is its best expert's, the ``topk_group`` best groups stay, and the top-k
+  are taken among their experts. The top-k weights are renormalised to sum
+  to 1 (``norm_topk_prob``), or else multiplied by
+  ``routed_scaling_factor``.
+* The layer holds experts ``[experts_first, experts_first + experts_held)``,
+  weights ``(held, d, ff)`` (all of them by default). It computes, for
+  every token routed to a held expert, that expert's contribution, and drops
+  none: the (token, expert) pairs are sorted by expert and each held
+  expert's rows go through one grouped matmul (``jax.lax.ragged_dot``), so
+  the work scales with the routed pairs, not with experts x capacity. Pairs
+  routed to experts held elsewhere sort last and are computed by the chips
+  that hold them.
+* Shared experts (DeepSeek-style) see every token, on every chip.
 
-* Router: softmax top-k over expert logits, optional shared experts
-  (DeepSeek-style) always active.
-* Dispatch: tokens are *sorted by expert id* and packed into a fixed
-  ``(E, capacity)`` grid (GShard-style capacity factor; overflow drops with
-  renormalized combine weights). The grouped tensor carries logical axes
-  ``("experts", "expert_cap", "embed")`` so expert parallelism shards the
-  leading axis over the ``model`` mesh axis; XLA SPMD materializes the
-  all-to-all around the gather/scatter.
-* Expert FFN: one einsum over the expert axis (SwiGLU), weights
-  ``(E, d, ff)`` sharded on E.
-
-The auxiliary load-balance loss (Switch-style) is returned for training.
+The Switch-style load-balance loss over all experts is returned for
+training.
 """
 from __future__ import annotations
 
@@ -27,31 +32,36 @@ from repro.models.layers import ffn, ffn_specs
 
 def moe_specs(cfg: ModelConfig) -> Specs:
     d, e, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    held = cfg.experts_held
     s: Specs = {
         "router": P((d, e), ("embed", "experts"), init="small"),
-        "w_gate": P((e, d, f), ("experts", "embed", "ff")),
-        "w_up": P((e, d, f), ("experts", "embed", "ff")),
-        "w_down": P((e, f, d), ("experts", "ff", "embed")),
+        "w_gate": P((held, d, f), ("experts", "embed", "ff")),
+        "w_up": P((held, d, f), ("experts", "embed", "ff")),
+        "w_down": P((held, f, d), ("experts", "ff", "embed")),
     }
     if cfg.n_shared_experts:
         s["shared"] = ffn_specs(d, cfg.moe_d_ff * cfg.n_shared_experts)
     return s
 
 
-def _capacity(n_tokens: int, cfg: ModelConfig) -> int:
-    from repro.sharding.optflags import opt
-
-    cf = 1.0 if opt("moe_cf1") else cfg.capacity_factor
-    cap = int(n_tokens * cfg.top_k * cf / cfg.n_experts)
-    return max(8, ((cap + 7) // 8) * 8)
-
-
 def route(params, cfg: ModelConfig, x2d):
-    """x2d: (T, d) -> (weights (T,k), experts (T,k), aux_loss)."""
-    logits = jnp.einsum("td,de->te", x2d, params["router"]).astype(jnp.float32)
+    """x2d: (T, d) -> (weights (T,k) float32, experts (T,k), aux_loss)."""
+    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
+                        params["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, cfg.top_k)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    scores = probs
+    if cfg.n_group > 1:
+        t = probs.shape[0]
+        grouped = probs.reshape(t, cfg.n_group, -1)
+        _, best = jax.lax.top_k(grouped.max(axis=-1), cfg.topk_group)
+        keep = jnp.zeros((t, cfg.n_group), bool).at[
+            jnp.arange(t)[:, None], best].set(True)
+        scores = jnp.where(keep[..., None], grouped, 0.0).reshape(t, -1)
+    weights, experts = jax.lax.top_k(scores, cfg.top_k)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-20)
+    else:
+        weights = weights * cfg.routed_scaling_factor
     # Switch-style load-balance aux loss.
     e = cfg.n_experts
     me = probs.mean(axis=0)
@@ -59,61 +69,38 @@ def route(params, cfg: ModelConfig, x2d):
         1.0 / (experts.size)
     )
     aux = e * jnp.sum(me * ce)
-    return weights.astype(x2d.dtype), experts, aux
+    return weights, experts, aux
 
 
 def moe_ffn(params, cfg: ModelConfig, x):
-    """x: (B, S, d) -> (B, S, d), aux_loss."""
+    """x: (B, S, d) -> (B, S, d), aux_loss: the held experts' part of the
+    routed result, plus the shared experts."""
     b, s, d = x.shape
-    t = b * s
+    t, k, held = b * s, cfg.top_k, cfg.experts_held
     x2d = x.reshape(t, d)
-    weights, experts, aux = route(params, cfg, x2d)
-    k, e = cfg.top_k, cfg.n_experts
-    cap = _capacity(t, cfg)
-
-    # ---- sort-based packing into (E, cap) ----
-    flat_expert = experts.reshape(-1)                      # (T*k,)
-    flat_token = jnp.repeat(jnp.arange(t), k)              # (T*k,)
-    flat_weight = weights.reshape(-1)
-    order = jnp.argsort(flat_expert, stable=True)
-    se, st, sw = flat_expert[order], flat_token[order], flat_weight[order]
-    # position within its expert group
-    ones = jnp.ones_like(se)
-    pos_in_e = jnp.cumsum(ones) - 1
-    group_start = jnp.searchsorted(se, jnp.arange(e), side="left")
-    slot = pos_in_e - group_start[se]                      # 0-based within expert
-    keep = slot < cap
-    # scatter token ids into the (E, cap) grid; empty slots point at T (zeros
-    # row); overflow entries scatter out-of-bounds and are dropped.
-    slot_or_oob = jnp.where(keep, slot, cap).astype(jnp.int32)
-    grid_tok = jnp.full((e, cap), t, jnp.int32)
-    grid_w = jnp.zeros((e, cap), flat_weight.dtype)
-    grid_tok = grid_tok.at[se, slot_or_oob].set(st.astype(jnp.int32), mode="drop")
-    grid_w = grid_w.at[se, slot_or_oob].set(sw, mode="drop")
-
-    x_pad = jnp.concatenate([x2d, jnp.zeros((1, d), x2d.dtype)], axis=0)
-    xg = x_pad[grid_tok]                                   # (E, cap, d)
-    # Pin the grouped layout: experts over the model axis (EP), capacity over
-    # data — the SPMD partitioner otherwise materializes (E, cap, d) fully
-    # replicated (tens of GiB at 160 experts).
-    from repro.sharding.partition import constrain
-
-    xg = constrain(xg, "model", "data", None)
-
-    # ---- expert SwiGLU over the expert axis ----
-    g = jnp.einsum("ecd,edf->ecf", xg, params["w_gate"])
-    u = jnp.einsum("ecd,edf->ecf", xg, params["w_up"])
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(xg.dtype) * u
-    h = constrain(h, "model", "data", None)
-    yg = jnp.einsum("ecf,efd->ecd", h, params["w_down"])   # (E, cap, d)
-    yg = constrain(yg, "model", "data", None)
-
-    # ---- combine: weighted scatter back to tokens ----
-    yw = yg * grid_w[..., None].astype(yg.dtype)
-    y2d = jnp.zeros((t + 1, d), yg.dtype).at[grid_tok.reshape(-1)].add(
-        yw.reshape(-1, d), mode="drop")[:t]
-    y2d = constrain(y2d, "data", None)
-
+    with jax.named_scope("moe_route"):
+        weights, experts, aux = route(params, cfg, x2d)
+    with jax.named_scope("moe_dispatch"):
+        local = experts.reshape(-1) - cfg.experts_first          # (T*k,)
+        mine = (local >= 0) & (local < held)
+        local = jnp.where(mine, local, held)     # held elsewhere: sorts last
+        order = jnp.argsort(local, stable=True)
+        token = order // k
+        xs = x2d[token]                                          # (T*k, d)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
+    with jax.named_scope("moe_experts"):
+        g = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
+        u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        ys = jax.lax.ragged_dot(h, params["w_down"], sizes)     # (T*k, d)
+    with jax.named_scope("moe_dispatch"):
+        back = jnp.argsort(order)                # each pair's sorted row
+        # Rows past the last group hold whatever the grouped matmul left
+        # there: select zeros, so nothing of theirs reaches the sum.
+        ys = jnp.where(mine[:, None], ys[back], 0).reshape(t, k, d)
+        y2d = jnp.einsum("tkd,tk->td", ys, weights,
+                         preferred_element_type=jnp.float32)
     if cfg.n_shared_experts:
-        y2d = y2d + ffn(params["shared"], x2d)
-    return y2d.reshape(b, s, d), aux
+        with jax.named_scope("shared_experts"):
+            y2d = y2d + ffn(params["shared"], x2d).astype(jnp.float32)
+    return y2d.astype(x.dtype).reshape(b, s, d), aux

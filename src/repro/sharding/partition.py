@@ -30,7 +30,6 @@ LOGICAL_RULES: list[tuple[str, tuple[str, ...]]] = [
     ("ff", ("model",)),
     ("vocab", ("model",)),
     ("embed", ("data",)),       # FSDP: weights gathered just-in-time
-    ("expert_cap", ("data",)),
     ("layers", ()),
     ("lora", ()),
 ]

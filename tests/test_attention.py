@@ -111,8 +111,9 @@ def test_mla_attention_shapes_and_decode():
     krope = jnp.zeros((b, s, cfg.rope_head_dim), jnp.float32)
     outs = []
     for t in range(s):
-        o, ckv, krope = mla_decode(params, cfg, x[:, t:t + 1], ckv, krope,
-                                   t)
+        o, c_row, k_row = mla_decode(params, cfg, x[:, t:t + 1], ckv, krope,
+                                     t)
+        ckv, krope = ckv.at[:, t].set(c_row[:, 0]), krope.at[:, t].set(k_row[:, 0])
         outs.append(o)
     dec = jnp.concatenate(outs, axis=1)
     np.testing.assert_allclose(dec, out, atol=1e-3, rtol=1e-2)
@@ -134,3 +135,48 @@ def test_chunked_scan_reference_matches_naive():
                                 kv_chunk=24)
         want = naive_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
+
+
+def test_yarn_frequencies_and_scale_match_published():
+    """DeepSeek-V2's rope: 64 dims, base 1e4, YaRN factor 40 over an original
+    4096 positions, beta_fast 32, beta_slow 1. Dimension i (of 32 pairs)
+    turns 4096 * f_i / (2 pi) times over the original context; pairs that
+    turn more than 32 times (i <= 10) keep f_i, those that turn less than
+    once (i >= 23) take f_i / 40, and a linear ramp joins them."""
+    import math
+
+    import repro.configs as C
+    from repro.models.attention import mla_softmax_scale
+    from repro.models.layers import yarn_frequencies, yarn_mscale
+
+    got = np.asarray(yarn_frequencies(64, 1e4, 40.0, 4096, 32.0, 1.0), np.float64)
+    f = 1e4 ** (-np.arange(32) / 32)
+    turns = 4096 * f / (2 * math.pi)
+    assert turns[10] > 32 > turns[11] and turns[22] > 1 > turns[23]
+    ramp = np.clip((np.arange(32) - 10) / 13, 0, 1)
+    np.testing.assert_allclose(got, f * (1 - ramp) + f / 40 * ramp, rtol=1e-6)
+    assert yarn_mscale(40.0, 0.707) == pytest.approx(0.1 * 0.707 * math.log(40) + 1)
+    assert yarn_mscale(40.0, 0.707) == pytest.approx(1.2608, abs=1e-4)
+    assert mla_softmax_scale(C.get("deepseek-v2-236b")) == pytest.approx(
+        192 ** -0.5 * 1.2608 ** 2, rel=1e-4)
+
+
+def test_mla_rope_rotates_interleaved_pairs():
+    """The published model rotates rope dims (2i, 2i + 1) together and
+    returns them de-interleaved: pair i lands at (i, i + r/2)."""
+    import dataclasses
+
+    import repro.configs as C
+    from repro.models.attention import mla_rope
+    from repro.models.layers import yarn_frequencies
+
+    cfg = dataclasses.replace(C.get("deepseek-v2-236b"), yarn_factor=1.0)
+    r, pos = cfg.rope_head_dim, 7
+    f = np.asarray(yarn_frequencies(r, cfg.rope_theta, 1.0, 4096, 32.0, 1.0))
+    for i in (0, 5, 31):
+        x = jnp.zeros((1, 1, 1, r)).at[..., 2 * i].set(1.0).at[..., 2 * i + 1].set(2.0)
+        out = np.asarray(mla_rope(cfg, x, jnp.array([[pos]]))[0, 0, 0])
+        c, s = np.cos(pos * f[i]), np.sin(pos * f[i])
+        want = np.zeros(r)
+        want[i], want[i + r // 2] = c - 2 * s, 2 * c + s
+        np.testing.assert_allclose(out, want, atol=1e-5)

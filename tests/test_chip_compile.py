@@ -135,23 +135,30 @@ def _materialized(hlo: str, shapes) -> list[str]:
     return found
 
 
+# what one chip holds of a layer, where it holds a share
+SHARE = {"deepseek-v2-236b": dict(experts_held=20)}   # 8-way expert parallel
+
+
 @pytest.mark.parametrize("arch, n_layers, batch, max_len", [
     ("granite-3-2b", 40, 32, 1024),        # the chat cell's shapes
     ("mistral-nemo-12b", 10, 16, 4096),    # one pipeline stage, reasoning's
+    ("deepseek-v2-236b", 6, 128, 4096),    # 1 dense + 5 MoE layers, long_decode's
 ])
 def test_decode_step_writes_only_new_rows(one_chip, arch, n_layers, batch,
                                           max_len):
-    """The layers read the stacked KV cache where it lies and the step
-    writes only the new token's rows into the donated cache: no temporary
-    near the cache's size, and no op that copies the whole stacked cache or
-    one layer's slice of it."""
+    """The layers read the stacked KV (or latent) cache where it lies and
+    the step writes only the new token's rows into the donated cache: no
+    temporary near the cache's size, and no op that copies a whole stacked
+    cache, one layer group's or one layer's slice of it."""
     import repro.configs as configs
 
-    cfg = dataclasses.replace(configs.get(arch), n_layers=n_layers)
+    cfg = dataclasses.replace(configs.get(arch), n_layers=n_layers,
+                              **SHARE.get(arch, {}))
     compiled, cache = _compile_decode_step(one_chip, cfg, batch, max_len)
     cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < cache_bytes / 8, (temp / 2 ** 30, cache_bytes / 2 ** 30)
-    stack = cache["k"].shape
-    assert not _materialized(compiled.as_text(),
-                             [stack, (1, *stack[1:]), stack[1:]])
+    groups = {n_layers, 1, n_layers - cfg.first_k_dense}
+    shapes = [(n, *a.shape[1:]) for a in cache.values() for n in groups]
+    shapes += [a.shape[1:] for a in cache.values()]
+    assert not _materialized(compiled.as_text(), shapes)
