@@ -298,7 +298,10 @@ class LanguageModel:
             if cfg.use_mla:
                 from repro.models.attention import mla_decode
                 from repro.models.layers import ffn as ffn_fn
-                from repro.models.moe import moe_ffn
+                from repro.models.moe import moe_ffn, split_experts
+
+                kd = cfg.first_k_dense
+                layers, experts = split_experts(params["layers"])
 
                 def body(x_, xs):
                     p_, i = xs
@@ -313,19 +316,19 @@ class LanguageModel:
                         if "ffn" in p_:
                             x_ = x_ + ffn_fn(p_["ffn"], h)
                         else:
-                            x_ = x_ + moe_ffn(p_["moe"], cfg, h)[0]
+                            x_ = x_ + moe_ffn(dict(p_["moe"], **experts), cfg,
+                                              h, layer=i - kd)[0]
                     return x_, (c_row.astype(ROW_DTYPE), k_row.astype(ROW_DTYPE))
 
                 # The leading dense layers and the MoE layers are two scans
                 # over one stacked latent cache; each reads its layers'
-                # slices where they lie, by index.
-                kd = cfg.first_k_dense
+                # slices where they lie, by index, as the MoE layers read
+                # their experts from the stack the scan closes over.
                 rows = []
-                for pkey, lo, hi in (("dense_layers", 0, kd),
-                                     ("layers", kd, cfg.n_layers)):
+                for p, lo, hi in ((params.get("dense_layers"), 0, kd),
+                                  (layers, kd, cfg.n_layers)):
                     if hi > lo:
-                        x, r = jax.lax.scan(
-                            body, x, (params[pkey], jnp.arange(lo, hi)))
+                        x, r = jax.lax.scan(body, x, (p, jnp.arange(lo, hi)))
                         rows.append(r)
                 cache = _write_rows(cache, {
                     n: jnp.concatenate([r[j] for r in rows])
@@ -333,9 +336,14 @@ class LanguageModel:
             else:
                 from repro.models.attention import gqa_decode
                 from repro.models.layers import ffn as ffn_fn
+                from repro.models.moe import moe_ffn, split_experts
+
+                layers, experts = split_experts(params["layers"])
+                # the MoE layers' places in the expert stack (none: dense)
+                idx = jnp.arange(cfg.n_layers) if experts else None
 
                 def body(x_, xs):
-                    p_, k_, v_ = xs
+                    p_, k_, v_, i = xs
                     with jax.named_scope("attention"):
                         h = rmsnorm(p_["ln1"], x_, cfg.norm_eps)
                         o, k_row, v_row = gqa_decode(p_["attn"], cfg, h, k_, v_, pos)
@@ -345,13 +353,13 @@ class LanguageModel:
                         if "ffn" in p_:
                             x_ = x_ + ffn_fn(p_["ffn"], h)
                         else:
-                            from repro.models.moe import moe_ffn
-                            y, _ = moe_ffn(p_["moe"], cfg, h)
+                            y, _ = moe_ffn(dict(p_["moe"], **experts), cfg, h,
+                                           layer=i)
                             x_ = x_ + y
                     return x_, (k_row.astype(ROW_DTYPE), v_row.astype(ROW_DTYPE))
 
                 x, (k_rows, v_rows) = jax.lax.scan(
-                    body, x, (params["layers"], cache["k"], cache["v"]))
+                    body, x, (layers, cache["k"], cache["v"], idx))
                 cache = _write_rows(cache, {"k": k_rows, "v": v_rows}, pos)
         elif cfg.family == "ssm":
             def body(x_, xs):

@@ -15,6 +15,14 @@ experts this chip holds.
   the work scales with the routed pairs, not with experts x capacity. Pairs
   routed to experts held elsewhere sort last and are computed by the chips
   that hold them.
+* The decode scans pass the stacked experts of all MoE layers and the
+  layer's place in that stack instead (``layer``): the grouped matmul then
+  runs over the whole stack, flattened to groups, with rows only in the
+  layer's groups, and reads that layer's experts where they lie. A slice of
+  the stack handed to the grouped-matmul kernel would be copied out first,
+  every layer of every step. On the TPU that kernel is megablox's, which
+  visits no empty group (``kernels/ops.py``); elsewhere ``ragged_dot`` over
+  the same groups. Training keeps one layer's weights and ``ragged_dot``.
 * Shared experts (DeepSeek-style) see every token, on every chip.
 
 The Switch-style load-balance loss over all experts is returned for
@@ -26,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models.base import P, Specs
 from repro.models.layers import ffn, ffn_specs
 
@@ -42,6 +51,38 @@ def moe_specs(cfg: ModelConfig) -> Specs:
     if cfg.n_shared_experts:
         s["shared"] = ffn_specs(d, cfg.moe_d_ff * cfg.n_shared_experts)
     return s
+
+
+EXPERTS = ("w_gate", "w_up", "w_down")
+
+
+def split_experts(layers):
+    """Stacked layer params -> (the rest, the stacked experts): what a decode
+    scan takes as its per-layer ``xs``, and what it closes over to pass to
+    ``moe_ffn`` whole. No experts (a dense stack): (layers, {})."""
+    if "moe" not in layers:
+        return layers, {}
+    moe = layers["moe"]
+    return (dict(layers, moe={n: v for n, v in moe.items() if n not in EXPERTS}),
+            {n: moe[n] for n in EXPERTS})
+
+
+def held_in_stack(w, sizes, layer):
+    """The stack ``w`` (L_moe, held, a, b) as L_moe * held groups (a bitcast),
+    and group sizes that are ``sizes`` at layer ``layer``'s held experts and
+    zero elsewhere."""
+    n, held = w.shape[:2]
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((n * held,), jnp.int32), sizes, (layer * held,))
+    return w.reshape(n * held, *w.shape[2:]), groups
+
+
+def _grouped_matmul(x, w, sizes, layer):
+    if layer is None:
+        return jax.lax.ragged_dot(x, w, sizes)
+    return jax.lax.platform_dependent(
+        x, *held_in_stack(w, sizes, layer),
+        tpu=ops.grouped_matmul_op, default=jax.lax.ragged_dot)
 
 
 def route(params, cfg: ModelConfig, x2d):
@@ -72,9 +113,11 @@ def route(params, cfg: ModelConfig, x2d):
     return weights, experts, aux
 
 
-def moe_ffn(params, cfg: ModelConfig, x):
+def moe_ffn(params, cfg: ModelConfig, x, layer=None):
     """x: (B, S, d) -> (B, S, d), aux_loss: the held experts' part of the
-    routed result, plus the shared experts."""
+    routed result, plus the shared experts. The experts in ``params`` are one
+    layer's, (held, d, ff); with ``layer``, every MoE layer's,
+    (L_moe, held, d, ff), of which layer ``layer``'s are used."""
     b, s, d = x.shape
     t, k, held = b * s, cfg.top_k, cfg.experts_held
     x2d = x.reshape(t, d)
@@ -89,10 +132,10 @@ def moe_ffn(params, cfg: ModelConfig, x):
         xs = x2d[token]                                          # (T*k, d)
         sizes = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
     with jax.named_scope("moe_experts"):
-        g = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
-        u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
+        g = _grouped_matmul(xs, params["w_gate"], sizes, layer)
+        u = _grouped_matmul(xs, params["w_up"], sizes, layer)
         h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-        ys = jax.lax.ragged_dot(h, params["w_down"], sizes)     # (T*k, d)
+        ys = _grouped_matmul(h, params["w_down"], sizes, layer)  # (T*k, d)
     with jax.named_scope("moe_dispatch"):
         back = jnp.argsort(order)                # each pair's sorted row
         # Rows past the last group hold whatever the grouped matmul left

@@ -162,3 +162,30 @@ def test_decode_step_writes_only_new_rows(one_chip, arch, n_layers, batch,
     shapes = [(n, *a.shape[1:]) for a in cache.values() for n in groups]
     shapes += [a.shape[1:] for a in cache.values()]
     assert not _materialized(compiled.as_text(), shapes)
+
+
+@pytest.mark.parametrize("arch, n_layers, held, batch, max_len", [
+    ("deepseek-v2-236b", 6, 20, 128, 4096),   # the long_decode cell's shapes
+    ("qwen3-moe-235b-a22b", 4, 16, 32, 1024),
+])
+def test_moe_decode_reads_held_experts_in_place(one_chip, arch, n_layers,
+                                                 held, batch, max_len):
+    """The MoE layers' grouped matmuls read each layer's held experts where
+    they lie in the stacked weights: no instruction materialises one layer's
+    (held, d, ff) or (held, ff, d) experts, and no temporary is as large as
+    one of them. The grouped matmuls are the Pallas kernel, under the
+    ``moe_experts`` scope."""
+    import repro.configs as configs
+
+    cfg = dataclasses.replace(configs.get(arch), n_layers=n_layers,
+                              experts_held=held)
+    compiled, _ = _compile_decode_step(one_chip, cfg, batch, max_len)
+    hlo = compiled.as_text()
+    d, f = cfg.d_model, cfg.moe_d_ff
+    assert not _materialized(hlo, [(held, d, f), (held, f, d)])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < held * d * f * 2, temp / 2 ** 20
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "/ffn/moe_experts/" in line]
+    assert len(kernels) == 3 and "ragged-dot" not in hlo
